@@ -139,14 +139,18 @@ def _leg(tokens, wall_s, **extra):
 def _worker_env(n_devices: int) -> dict:
     """Environment for a subprocess bench worker that needs its own
     host-platform device mesh (the main bench process must keep one device
-    so the other sections' timings do not change)."""
+    so the other sections' timings do not change).
+
+    The worker is a host-mesh rehearsal by design, so it is pinned to the
+    CPU whatever the parent runs on: a chip belongs to one process, and the
+    parent may already hold it. No chip number comes out of a worker."""
     import os
 
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count"
                           f"={n_devices}").strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))), "src"),
@@ -662,9 +666,9 @@ _OVERLAP_WORKER = """
 import json, time
 import numpy as np
 import jax, jax.numpy as jnp
-from repro.compat import set_mesh
 from repro.configs.base import MoEConfig
 from repro.core import synthetic_trace
+from repro.launch.mesh import make_mesh
 from repro.models.layers import ParallelContext
 from repro.models.moe import init_moe, moe_apply
 from repro.serving import rounds_from_trace
@@ -672,7 +676,7 @@ import dataclasses
 
 n_dev = {n_devices}
 n_experts = {n_experts}
-mesh = jax.make_mesh((n_dev,), ("model",))
+mesh = make_mesh((n_dev,), ("model",))
 moe = MoEConfig(n_experts=n_experts, top_k=2, d_ff={d_ff},
                 capacity_factor=2.0)
 p = init_moe(jax.random.PRNGKey(0), {d_model}, moe, jnp.float32)
@@ -685,7 +689,7 @@ shapes = {{"decode": ({t_decode}, 1, {d_model}),
           "prefill": ({n_devices}, {s_prefill}, {d_model})}}
 rec = {{"n_devices": n_dev, "n_experts": n_experts, "rounds": len(rounds)}}
 max_abs = 0.0
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     for name, shape in shapes.items():
         x = jax.random.normal(jax.random.PRNGKey(1), shape)
         outs = {{}}
@@ -709,6 +713,7 @@ with set_mesh(mesh):
                                   / rec["sync"][name + "_tok_per_s"])
 rec["max_abs_diff"] = max_abs
 rec["ok"] = bool(max_abs < 1e-5)
+rec["platform"] = jax.devices()[0].platform   # always "cpu"
 print("OVERLAP_JSON " + json.dumps(rec))
 """
 
@@ -1443,6 +1448,7 @@ rec["ok"] = bool(
     "device_loss" in kinds and "nan" in kinds
     and rec["survivors"] < n_dev
     and rec["complete"] and rec["identical"])
+rec["platform"] = jax.devices()[0].platform   # always "cpu"
 print("CHAOS_JSON " + json.dumps(rec))
 """
 
@@ -1725,6 +1731,7 @@ rec["ok"] = bool(
     rec["dispatch_rounds"] >= 1 and rec["fault_events"] >= 1
     and rec["adoptions"] >= 1 and rec["interleaved"] and rec["ordered"]
     and rec["identical"] and rec["chrome_events"] >= rec["records"])
+rec["platform"] = jax.devices()[0].platform   # always "cpu"
 print("TRACE_JSON " + json.dumps(rec))
 """
 
@@ -1919,6 +1926,8 @@ def main() -> int:
     ap.add_argument("--json", default=None,
                     help="write section records to this JSON file")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     sections = {}
     run_classic = args.all or not (args.chunked or args.drift or args.multi

@@ -20,8 +20,6 @@ if "xla_force_host_platform_device_count" not in os.environ.get(
 
 import jax
 import jax.numpy as jnp
-
-from repro.compat import set_mesh
 import numpy as np
 
 
@@ -30,11 +28,12 @@ def main():
     from repro.core import aurora_schedule, synthetic_trace
     from repro.distributed import (aurora_rounds_from_schedule,
                                    round_robin_rounds)
+    from repro.launch.mesh import make_mesh
     from repro.models.layers import ParallelContext
     from repro.models.moe import init_moe, moe_apply_ep
 
     n = 8
-    mesh = jax.make_mesh((n,), ("model",))
+    mesh = make_mesh((n,), ("model",))
     moe = MoEConfig(n_experts=n, top_k=2, d_ff=128, capacity_factor=4.0)
     params = init_moe(jax.random.PRNGKey(0), 64, moe, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 32, 64))
@@ -50,7 +49,7 @@ def main():
         pc = ParallelContext(mesh=mesh, data_axes=(), model_axis="model",
                              ep_axes=("model",), token_axes=("model",),
                              moe_impl=impl, aurora_rounds=aurora_rounds)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             y, aux = moe_apply_ep(params, x, moe, "swiglu", pc)
         return np.asarray(y)
 

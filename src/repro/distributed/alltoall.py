@@ -30,8 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import axis_size, shard_map
-
 
 # ---------------------------------------------------------------------------
 # Round construction (host side)
@@ -139,7 +137,7 @@ def flat_axis_index(axis_names):
     """Row-major flattened device index over ``axis_names`` (traced)."""
     me = jnp.zeros((), jnp.int32)
     for ax in axis_names:
-        me = me * axis_size(ax) + jax.lax.axis_index(ax)
+        me = me * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     return me
 
 
@@ -254,7 +252,7 @@ def _replicated_counts(idx, valid, n_experts: int, token_axes):
     t_loc = cnt.shape[0]
     n_shards = 1
     for ax in token_axes:
-        n_shards *= axis_size(ax)
+        n_shards *= jax.lax.axis_size(ax)
     shard = flat_axis_index(token_axes)
     full = jnp.zeros((n_shards * t_loc, n_experts), jnp.float32)
     full = jax.lax.dynamic_update_slice(full, cnt, (shard * t_loc, 0))
@@ -268,7 +266,7 @@ def _local_dispatch_combine(xt, valid, router_w, experts, moe, act,
     t_loc, d = xt.shape
     n_ep = 1
     for ax in ep_axes:
-        n_ep *= axis_size(ax)
+        n_ep *= jax.lax.axis_size(ax)
     e = moe.n_experts
 
     buf, combine, aux, idx = _scatter_buckets(xt, valid, router_w, moe,
@@ -351,7 +349,7 @@ def ep_dispatch_combine(xt, router_w, experts, moe, act, pc,
     out_specs = (P(token_axes, None), P())
     if return_counts:
         out_specs = out_specs + (P(),)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda xs, vs, rw, ex: body(
             xs, vs, rw, ex, moe, act, ep_axes, token_axes, rounds,
             return_counts=return_counts, spec=spec),

@@ -36,8 +36,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
-
 from .alltoall import _replicated_counts, _scatter_buckets, flat_axis_index
 
 
@@ -59,7 +57,7 @@ def pipelined_local_dispatch_combine(xt, valid, router_w, experts, moe, act,
     t_loc, d = xt.shape
     n_ep = 1
     for ax in ep_axes:
-        n_ep *= axis_size(ax)
+        n_ep *= jax.lax.axis_size(ax)
     e = moe.n_experts
     axis_name = tuple(ep_axes) if len(ep_axes) > 1 else ep_axes[0]
     me = flat_axis_index(ep_axes)

@@ -12,20 +12,24 @@ them.
 TPU mapping: grid (E, C/bc, F/bf) with the f-axis innermost as a reduction —
 each (e, c) output block accumulates partial ``h_blk @ w_down_blk`` products
 across f-steps in a float32 VMEM scratch accumulator, flushing to the output
-on the last step. Block shapes keep the working set in VMEM
-(x (bc,d) + w (d,bf)·2 + w_down (bf,d) + acc (bc,d)f32 ≈ 11 MB at
-bc=bf=128, d=7168) and all matmul dims are multiples of 128 for the MXU.
+on the last step. Block shapes keep the working set in VMEM: x (bc,d),
+w_gate/w_up (d,bf), w_down (bf,d) and the output block, each double
+buffered, plus the (bc,d) f32 accumulator — about 12 MiB at bc=bf=128,
+d=4096 in bf16. Every matmul dim is a multiple of 128 for the MXU.
 
 **Ragged groups** (``group_sizes``): the serving dispatch path routes only a
-handful of real tokens per step, so most capacity rows are zero padding. A
-per-expert row count rides in SMEM (like ``decode_attn``'s ``valid_len``)
-and every (e, c)-block whose row range starts at or beyond its group's fill
-level skips all three matmuls — the MegaBlocks-style dropless-group idea at
+handful of real tokens per step, so most capacity rows are zero padding.
+The whole (E,) row-count array rides in SMEM, indexed by the expert grid
+index (the TPU compiler refuses a rank-1 ``(1,)`` SMEM block), and every
+(e, c)-block whose row range starts at or beyond its group's fill level
+skips all three matmuls — the MegaBlocks-style dropless-group idea at
 block granularity. Skipped blocks flush the zero accumulator, which equals
 the dense result exactly: padding rows are zero and FFN(0) == 0.
 
-Validated against ``ref.moe_ffn_ref`` in interpret mode (this container is
-CPU-only; TPU is the target).
+Validated against ``ref.moe_ffn_ref`` in interpret mode, compiled for a
+described TPU v5e at phi3.5-moe widths (E=16, d=4096, f=6400; capacity 8
+and 256: ``tests/test_tpu_compile.py``), and run on a v5e chip inside the
+phi3.5-moe serving path (``chip_smoke.py``).
 """
 
 from __future__ import annotations
@@ -36,8 +40,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.compat import pallas_compiler_params
 
 
 def align_capacity(cap: int, block_c: int) -> int:
@@ -66,7 +68,7 @@ def _kernel(gs_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref, *,
     # c*bc + 1 routed rows the whole block is zero padding — skip the MXU
     # work. (Partially-filled blocks still run; their pad rows are zero
     # inputs, and FFN(0) == 0 keeps the output exact.)
-    live = gs_ref[0] > pl.program_id(1) * block_c
+    live = gs_ref[pl.program_id(0)] > pl.program_id(1) * block_c
 
     @pl.when(live)
     def _compute():
@@ -113,8 +115,7 @@ def moe_gmm(x, w_gate, w_up, w_down, *, group_sizes=None, act: str = "swiglu",
         functools.partial(_kernel, act=act, n_f=n_f, block_c=bc),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1,), lambda e_, c_, f_: (e_,),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),     # whole (E,) array
             pl.BlockSpec((1, bc, d), lambda e_, c_, f_: (e_, c_, 0)),
             pl.BlockSpec((1, d, bf), lambda e_, c_, f_: (e_, 0, f_)),
             pl.BlockSpec((1, d, bf), lambda e_, c_, f_: (e_, 0, f_)),
@@ -123,7 +124,7 @@ def moe_gmm(x, w_gate, w_up, w_down, *, group_sizes=None, act: str = "swiglu",
         out_specs=pl.BlockSpec((1, bc, d), lambda e_, c_, f_: (e_, c_, 0)),
         out_shape=jax.ShapeDtypeStruct((e, c, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, d), jnp.float32)],
-        compiler_params=pallas_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(group_sizes, x, w_gate, w_up, w_down)

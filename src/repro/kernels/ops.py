@@ -1,10 +1,14 @@
 """Jit'd wrappers over the Pallas kernels with automatic fallback.
 
 ``interpret`` selects the execution mode everywhere:
-- On TPU: compiled Pallas (the production path).
-- On CPU (this container): ``interpret=True`` executes the kernel body in
-  Python for correctness validation; ``interpret=None`` (auto) keeps the
-  pure-jnp reference so serving and tests stay fast.
+- On TPU: compiled Pallas (the production path). Both kernels have been
+  compiled for a v5e at phi3.5-moe widths and run on one inside the serving
+  path (``chip_smoke.py``).
+- On CPU: ``interpret=True`` executes the kernel body in Python for
+  correctness validation; ``interpret=None`` (auto) keeps the pure-jnp
+  reference so serving and tests stay fast. A compiled program that ran
+  the kernels holds ``tpu_custom_call``; ``chip_smoke.py`` checks for it,
+  so a run that fell back is not mistaken for a kernel run.
 
 The ``*_auto`` entry points additionally derive legal block shapes from the
 runtime array shapes (capacity buckets and cache lengths are workload-sized,
@@ -31,12 +35,21 @@ def use_pallas(interpret: bool | None = None) -> bool:
     return on_tpu() or bool(interpret)
 
 
-def _divisor_block(n: int, block: int) -> int:
-    """Largest block size <= ``block`` that divides ``n`` exactly."""
-    b = max(min(block, n), 1)
-    while n % b:
-        b -= 1
-    return b
+def _divisor_block(n: int, block: int, align: int) -> int:
+    """Largest block <= ``block`` that divides ``n`` and is a multiple of
+    ``align`` (128 on the lane axis, 8 on the sublane axis) — or ``n``
+    itself when ``n <= block``, since a whole-axis block is always legal.
+
+    Raises ``ValueError`` where no such block exists: an unaligned block
+    passes interpret mode but is refused by the TPU compiler.
+    """
+    if n <= block:
+        return n
+    for b in range(block - block % align, 0, -align):
+        if n % b == 0:
+            return b
+    raise ValueError(f"no block of at most {block} divides {n} in multiples "
+                     f"of {align}; pad the axis or change the block size")
 
 
 def moe_ffn(x, w_gate, w_up, w_down, act: str = "swiglu",
@@ -53,8 +66,8 @@ def moe_ffn(x, w_gate, w_up, w_down, act: str = "swiglu",
                                group_sizes=group_sizes)
     return moe_gmm(x, w_gate, w_up, w_down, act=act,
                    group_sizes=group_sizes,
-                   block_c=_divisor_block(x.shape[1], block_c),
-                   block_f=_divisor_block(w_gate.shape[-1], block_f),
+                   block_c=_divisor_block(x.shape[1], block_c, 8),
+                   block_f=_divisor_block(w_gate.shape[-1], block_f, 128),
                    interpret=bool(interpret) if interpret is not None
                    else not on_tpu())
 
@@ -64,8 +77,8 @@ def decode_attn_auto(q, k, v, valid_len, block_s: int = 512,
     """Decode-step attention over a per-slot cache, impl auto-selected.
 
     q: (B, H, D); k/v: (B, S, Hkv, D); valid_len scalar or (B,) fill levels
-    (broadcast to every batch row). Picks the largest KV block that divides
-    the cache capacity, so workload-sized caches never trip the grid rules.
+    (broadcast to every batch row). Picks the largest sublane-aligned KV
+    block that divides the cache capacity (``_divisor_block``).
     """
     b = q.shape[0]
     valid_len = jnp.broadcast_to(
@@ -73,6 +86,6 @@ def decode_attn_auto(q, k, v, valid_len, block_s: int = 512,
     if not use_pallas(interpret):
         return ref.decode_attn_ref(q, k, v, valid_len)
     return decode_attn(q, k, v, valid_len,
-                       block_s=_divisor_block(k.shape[1], block_s),
+                       block_s=_divisor_block(k.shape[1], block_s, 8),
                        interpret=bool(interpret) if interpret is not None
                        else not on_tpu())

@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run (deliverable e).
 
 Lowers + compiles the appropriate step for every supported
@@ -14,9 +11,8 @@ and records ``memory_analysis()`` (fits-in-HBM evidence),
 histogram parsed from the compiled HLO. Failures here (sharding mismatch,
 unsupported collective) are bugs in the system.
 
-The XLA_FLAGS line above MUST run before any other import — jax locks the
-device count at first init. Do not import this module from test/bench
-processes (they must see one device); invoke it as
+``main`` splits the host platform into 512 devices before jax initializes
+(jax locks the device count at first init), so run it as its own process:
 ``PYTHONPATH=src python -m repro.launch.dryrun --arch ... --shape ...``.
 
 Usage:
@@ -26,6 +22,7 @@ Usage:
 
 import argparse
 import json
+import os
 import time
 import traceback
 
@@ -34,7 +31,6 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
             moe_impl: str = "ep", out_dir: str | None = None,
             calibrate: bool = True) -> dict:
     import jax
-    from repro.compat import set_mesh
     from repro.configs import SHAPES, get_config
     from repro.launch.mesh import make_production_mesh
     from repro.launch import specs as S
@@ -53,7 +49,7 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
 
     t0 = time.time()
     mesh = make_production_mesh(multi_pod=multi_pod)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         step_fn, args = S.lowering_args(cfg, shape, mesh, moe_impl=moe_impl)
         # Donation: train aliases params+opt in place, serving aliases the
         # KV/SSM cache — no full-state copy per step (§Perf iteration 1).
@@ -117,6 +113,8 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
 
 
 def main() -> int:
+    from repro.launch.mesh import force_host_device_count
+    force_host_device_count(512)
     from repro.configs import ARCH_IDS, SHAPES
 
     ap = argparse.ArgumentParser()

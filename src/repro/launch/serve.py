@@ -128,6 +128,8 @@ def main() -> int:
         # device count (no-op when real devices exist and the flag is set).
         from repro.launch.mesh import force_host_device_count
         force_host_device_count(args.mesh)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     telemetry = None
     if args.trace_out or args.metrics_out:

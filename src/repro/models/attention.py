@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from .layers import (apply_mrope, apply_rope, attention_core, rmsnorm)
 
@@ -70,6 +71,39 @@ def _cache_write(cache_arr, new, slot, pc):
     mask = (jnp.arange(cap) == slot).reshape(
         (1, cap) + (1,) * (cache_arr.ndim - 2))
     return jnp.where(mask, new.astype(cache_arr.dtype), cache_arr)
+
+
+def _decode_attn_kernel(q, k, v, valid, pc):
+    """Kernelized decode attention: stream the per-slot cache past the
+    single query through ``kernels.ops.decode_attn_auto`` (Pallas
+    flash-decode on TPU / interpret; jnp oracle on CPU — same masking math).
+
+    A Pallas kernel is opaque to the partitioner, so under a mesh it runs
+    per shard inside ``shard_map``: batch over the data axes and kv heads
+    (with their query groups) over the model axis, each where it divides.
+    """
+    from repro.kernels.ops import decode_attn_auto
+
+    kc = pc.kernels
+    b = q.shape[0]
+    valid = jnp.broadcast_to(jnp.asarray(valid, jnp.int32).reshape(-1), (b,))
+
+    def attn(q, k, v, valid):
+        return decode_attn_auto(q, k, v, valid, block_s=kc.block_s,
+                                interpret=kc.interpret)
+
+    if pc.mesh is None:
+        return attn(q, k, v, valid)
+    nb = 1
+    for a in pc.data_axes:
+        nb *= pc.mesh.shape[a]
+    b_ax = pc.data_axes if pc.data_axes and b % nb == 0 else None
+    h_ax = (pc.model_axis if pc.model_axis is not None
+            and k.shape[2] % pc.mesh.shape[pc.model_axis] == 0 else None)
+    q_spec, kv_spec = P(b_ax, h_ax, None), P(b_ax, None, h_ax, None)
+    return jax.shard_map(attn, mesh=pc.mesh,
+                         in_specs=(q_spec, kv_spec, kv_spec, P(b_ax)),
+                         out_specs=q_spec, check_vma=False)(q, k, v, valid)
 
 
 def _rope_qk(cfg, q, k, pos, pos3):
@@ -192,15 +226,8 @@ def attn_block(p, x, *, cfg, pos, window=None, cache=None, length=None,
         cv = _cache_write(cache["v"], v, slot, pc)
         new_cache = {"k": ck, "v": cv}
         valid = jnp.minimum(length + 1, cap)
-        kc = pc.kernels if pc is not None else None
-        if kc is not None:
-            # Kernelized hot path: stream the per-slot cache past the single
-            # query through kernels.ops.decode_attn_auto (Pallas flash-decode
-            # on TPU / interpret; jnp oracle on CPU — same masking math).
-            from repro.kernels.ops import decode_attn_auto
-            out = decode_attn_auto(q[:, 0], ck, cv, valid,
-                                   block_s=kc.block_s,
-                                   interpret=kc.interpret)[:, None]
+        if pc is not None and pc.kernels is not None:
+            out = _decode_attn_kernel(q[:, 0], ck, cv, valid, pc)[:, None]
         else:
             out = attention_core(q, ck, cv, causal_offset=None, window=None,
                                  valid_len=valid, flash_block=flash_block)

@@ -26,6 +26,12 @@ class KernelConfig:
 
     ``interpret``: None = auto (compiled Pallas on TPU, pure-jnp reference on
     CPU); True forces Pallas interpret mode (correctness validation on CPU).
+
+    The blocks are upper bounds: ``kernels.ops`` takes the largest aligned
+    divisor of the runtime axis. These defaults compile for a TPU v5e at
+    phi3.5-moe widths (``tests/test_tpu_compile.py``); ``block_s=512`` keeps
+    a (512, 8·128) bf16 key/value block pair, double buffered, at 4 MiB of
+    the 16 MiB scoped VMEM.
     """
 
     interpret: bool | None = None

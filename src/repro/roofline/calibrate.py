@@ -72,7 +72,6 @@ def _main_segments(cfg) -> tuple[list, list]:
 
 def _measure(cfg, shape, mesh, moe_impl: str) -> dict:
     import jax
-    from repro.compat import set_mesh
     from repro.launch import specs as S
 
     # UNROLLED lowering: a lax.scan body is cost-counted once regardless of
@@ -81,7 +80,7 @@ def _measure(cfg, shape, mesh, moe_impl: str) -> dict:
     step_fn, args = S.lowering_args(cfg, shape, mesh, moe_impl=moe_impl,
                                     unroll=True)
     donate = (0, 1) if shape.kind == "train" else (2,)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         compiled = jax.jit(step_fn, donate_argnums=donate).lower(*args) \
             .compile()
     cost = compiled.cost_analysis() or {}

@@ -34,7 +34,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from repro.compat import set_mesh
 from repro.core.errors import PlanError
 from repro.core.schedule import aurora_schedule
 from repro.core.traffic import MoETrace, strip_diagonal
@@ -188,11 +187,11 @@ def _require_aurora(pc) -> None:
 
 
 def _with_mesh(mesh):
-    """Step wrapper: run a compiled step under the mesh context (legacy jax
-    resolves bare ``PartitionSpec`` sharding constraints from it)."""
+    """Step wrapper: run a compiled step under the mesh context (bare
+    ``PartitionSpec`` sharding constraints resolve against it)."""
     def wrap(fn):
         def run(*args, **kwargs):
-            with set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 return fn(*args, **kwargs)
         return run
     return wrap

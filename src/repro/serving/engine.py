@@ -246,8 +246,8 @@ class ContinuousEngine:
                           if model.cfg.moe is not None else None)
         self._jit = config.jit
         # Distributed engines wrap every compiled step so it runs under the
-        # mesh context (``with_sharding_constraint`` needs an active mesh on
-        # legacy jax); identity for the single-device engines.
+        # mesh context (bare ``PartitionSpec`` sharding constraints resolve
+        # against it); identity for the single-device engines.
         self._step_wrapper = config.step_wrapper or (lambda fn: fn)
         # Optional telemetry hub (``config.telemetry``): compiled steps get
         # span-wrapped in ``_build_steps`` and the scheduler publishes

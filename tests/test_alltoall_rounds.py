@@ -16,9 +16,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.core import aurora_schedule, synthetic_trace
 from repro.core.schedule import CommSchedule, Slot, validate_permutation_slots
+from repro.launch.mesh import make_mesh
 from repro.distributed import (aurora_rounds_from_schedule, ep_all_to_all,
                                round_robin_rounds)
 
@@ -141,13 +141,13 @@ def test_ep_all_to_all_identity_on_one_device_mesh():
     """A 1-device mesh's exchange is the identity for every lowering: the
     monolithic all_to_all, an empty round schedule, and the BvN-derived
     rounds of a 1-device schedule (== empty)."""
-    mesh = jax.make_mesh((1,), ("ep",))
+    mesh = make_mesh((1,), ("ep",))
     x = jnp.arange(24, dtype=jnp.float32).reshape(1, 6, 4)
     rounds_1 = aurora_rounds_from_schedule(
         aurora_schedule(np.zeros((1, 1))), 1)
 
     for rounds in (None, (), rounds_1):
-        y = jax.jit(shard_map(
+        y = jax.jit(jax.shard_map(
             lambda b, rounds=rounds: ep_all_to_all(b, ("ep",), rounds),
             mesh=mesh, in_specs=P("ep"), out_specs=P("ep"),
             check_vma=False))(x)

@@ -29,12 +29,12 @@ def test_ep_dispatch_matches_dense():
     """EP shard_map all_to_all dispatch ≡ the dense reference dispatch."""
     _run("""
     import jax, jax.numpy as jnp, numpy as np, dataclasses
-    from repro.compat import set_mesh, shard_map
+    from repro.launch.mesh import make_mesh
     from repro.models.moe import moe_apply_dense, moe_apply_ep, init_moe
     from repro.models.layers import ParallelContext
     from repro.configs.base import MoEConfig
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     moe = MoEConfig(n_experts=8, top_k=2, d_ff=64, capacity_factor=8.0)
     key = jax.random.PRNGKey(0)
     p = init_moe(key, 32, moe, jnp.float32)
@@ -44,7 +44,7 @@ def test_ep_dispatch_matches_dense():
                          ep_axes=("data", "model"),
                          token_axes=("data", "model"), moe_impl="ep")
     y_dense, aux_d = moe_apply_dense(p, x, moe, "swiglu")
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         y_ep, aux_e = moe_apply_ep(p, x, moe, "swiglu", pc)
     # capacity_factor is large enough that no tokens drop in either path;
     # EP capacity is per-source-device so bucket POSITIONS differ, but the
@@ -64,15 +64,15 @@ def test_aurora_rounds_match_all_to_all():
     """The scheduled ppermute exchange ≡ monolithic lax.all_to_all."""
     _run("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.compat import set_mesh, shard_map
+    from repro.launch.mesh import make_mesh
     from jax.sharding import PartitionSpec as P
     from repro.distributed.alltoall import ep_all_to_all, round_robin_rounds
 
-    mesh = jax.make_mesh((8,), ("ep",))
+    mesh = make_mesh((8,), ("ep",))
     x = jax.random.normal(jax.random.PRNGKey(0), (8 * 8, 4, 16))
 
     def f(rounds):
-        return shard_map(
+        return jax.shard_map(
             lambda b: ep_all_to_all(b, ("ep",), rounds),
             mesh=mesh, in_specs=P("ep"), out_specs=P("ep"),
             check_vma=False)(x)
@@ -88,7 +88,7 @@ def test_aurora_schedule_rounds_cover_all_pairs():
     """BvN-derived rounds (from a real schedule) also reproduce all_to_all."""
     _run("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.compat import set_mesh, shard_map
+    from repro.launch.mesh import make_mesh
     from jax.sharding import PartitionSpec as P
     from repro.core import synthetic_trace, aurora_schedule
     from repro.distributed.alltoall import (ep_all_to_all,
@@ -107,10 +107,10 @@ def test_aurora_schedule_rounds_cover_all_pairs():
     off = ~np.eye(n, dtype=bool)
     assert (seen[off] == 1).all(), seen
 
-    mesh = jax.make_mesh((8,), ("ep",))
+    mesh = make_mesh((8,), ("ep",))
     x = jax.random.normal(jax.random.PRNGKey(0), (8 * 8, 4, 16))
     def f(rounds):
-        return shard_map(
+        return jax.shard_map(
             lambda b: ep_all_to_all(b, ("ep",), rounds),
             mesh=mesh, in_specs=P("ep"), out_specs=P("ep"),
             check_vma=False)(x)
@@ -125,7 +125,7 @@ def test_full_moe_layer_aurora_schedule_matches_dense():
     reference — the schedule changes when bytes move, never what arrives."""
     _run("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.compat import set_mesh, shard_map
+    from repro.launch.mesh import make_mesh
     from repro.configs.base import MoEConfig
     from repro.core import aurora_schedule, synthetic_trace
     from repro.distributed import aurora_rounds_from_schedule
@@ -133,7 +133,7 @@ def test_full_moe_layer_aurora_schedule_matches_dense():
     from repro.models.moe import init_moe, moe_apply_dense, moe_apply_ep
 
     n = 8
-    mesh = jax.make_mesh((n,), ("model",))
+    mesh = make_mesh((n,), ("model",))
     moe = MoEConfig(n_experts=n, top_k=2, d_ff=64, capacity_factor=8.0)
     p = init_moe(jax.random.PRNGKey(0), 32, moe, jnp.float32)
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32))
@@ -145,7 +145,7 @@ def test_full_moe_layer_aurora_schedule_matches_dense():
                          ep_axes=("model",), token_axes=("model",),
                          moe_impl="aurora", aurora_rounds=rounds)
     y_ref, _ = moe_apply_dense(p, x, moe, "swiglu")
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         y_aur, _ = moe_apply_ep(p, x, moe, "swiglu", pc)
     np.testing.assert_allclose(np.asarray(y_aur), np.asarray(y_ref),
                                rtol=2e-4, atol=2e-4)
@@ -158,7 +158,7 @@ def test_moe_smoke_on_mesh_multipod_axes():
     mesh with EP over model only."""
     _run("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.compat import set_mesh, shard_map
+    from repro.launch.mesh import make_mesh
     from repro.configs import get_config
     from repro.models import Model, cross_entropy
     from repro.sharding import make_pc
@@ -166,7 +166,7 @@ def test_moe_smoke_on_mesh_multipod_axes():
 
     cfg = get_config("phi3.5-moe-42b-a6.6b").reduced()
     # 4 experts over a model axis of 2 → EP=2, experts_per_device=2.
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
     pc = make_pc(cfg, mesh, moe_impl="ep")
     # 4 experts on data×model = 4 → the widest EP axis is chosen; the pod
     # axis must never join it.
@@ -176,7 +176,7 @@ def test_moe_smoke_on_mesh_multipod_axes():
     params = model.init(jax.random.PRNGKey(0))
     tokens = jax.random.randint(jax.random.PRNGKey(1), (4, 16), 0, cfg.vocab)
 
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         def loss_fn(p):
             logits, aux = model.train_logits(p, {"tokens": tokens},
                                              remat=False)

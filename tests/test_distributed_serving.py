@@ -23,7 +23,12 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def _run(body: str) -> str:
     env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    # Single-threaded Eigen: a multi-threaded CPU dot splits its
+    # reduction by the operand shape, so the same rows computed in a
+    # smaller matmul (one pipeline chunk) would round differently and
+    # the byte-identity checks below would compare the thread split.
+    env["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=8 "
+                        "--xla_cpu_multi_thread_eigen=false")
     env["PYTHONPATH"] = os.path.join(REPO, "src")
     out = subprocess.run([sys.executable, "-c", textwrap.dedent(body)],
                          env=env, capture_output=True, text=True, timeout=600)
@@ -39,16 +44,16 @@ def test_pipelined_dispatch_matches_sync_and_counts_match_dense():
     _run("""
     import dataclasses
     import jax, jax.numpy as jnp, numpy as np
-    from repro.compat import set_mesh
     from repro.configs.base import MoEConfig
     from repro.core import aurora_schedule, synthetic_trace
+    from repro.launch.mesh import make_mesh
     from repro.distributed import (aurora_rounds_from_schedule,
                                    pipelined_dispatch_combine)
     from repro.models.layers import ParallelContext
     from repro.models.moe import init_moe, moe_apply_dense, moe_apply_ep
     from repro.serving import rounds_from_trace
 
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = make_mesh((8,), ("model",))
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 32))
     for e in (8, 16):                       # experts_per_device 1 and 2
         moe = MoEConfig(n_experts=e, top_k=2, d_ff=64, capacity_factor=8.0)
@@ -61,7 +66,7 @@ def test_pipelined_dispatch_matches_sync_and_counts_match_dense():
         pc_pipe = dataclasses.replace(pc, ep_overlap=True)
         y_ref, _, c_ref = jax.jit(lambda x, p=p, moe=moe: moe_apply_dense(
             p, x, moe, "swiglu", return_counts=True))(x)
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             y_sync, _, c_sync = jax.jit(
                 lambda x, p=p, moe=moe, pc=pc: moe_apply_ep(
                     p, x, moe, "swiglu", pc, return_counts=True))(x)
@@ -77,7 +82,7 @@ def test_pipelined_dispatch_matches_sync_and_counts_match_dense():
         np.testing.assert_array_equal(np.asarray(c_sync), np.asarray(c_ref))
         np.testing.assert_array_equal(np.asarray(c_pipe), np.asarray(c_ref))
         # The standalone wrapper (forced pipeline) agrees too.
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             xt = x.reshape(-1, 32)
             y_w, _ = jax.jit(lambda xt, p=p, moe=moe, pc=pc:
                              pipelined_dispatch_combine(

@@ -103,7 +103,7 @@ def test_moe_apply_kernel_matches_dense(arch, t):
     np.testing.assert_array_equal(np.asarray(c_k), np.asarray(c_d))
 
 
-@pytest.mark.parametrize("block_c", [3, 8, 128])
+@pytest.mark.parametrize("block_c", [16, 8, 128])
 def test_moe_apply_kernel_interpret_capacity_alignment(block_c):
     """Regression: ``capacity(multiple=8)`` need not divide into the kernel's
     ``block_c`` grid — the kernel path pads the bucket to ``align_capacity``
@@ -114,7 +114,9 @@ def test_moe_apply_kernel_interpret_capacity_alignment(block_c):
     cfg = get_config("phi3.5-moe-42b-a6.6b").reduced()
     moe = cfg.moe
     p = init_moe(jax.random.PRNGKey(0), cfg.d_model, moe, jnp.float32)
-    t = 16                                  # capacity() -> 16, not 8-aligned
+    t = 32                                  # capacity() -> 24: 8 divides
+    #                                         it, 16 pads it to 32, 128
+    #                                         shrinks to it
     cap = capacity(t, moe.top_k, moe.n_experts, moe.capacity_factor)
     assert align_capacity(cap, block_c) % min(block_c, cap) == 0
     x = jax.random.normal(jax.random.PRNGKey(1), (t, cfg.d_model),
@@ -159,11 +161,32 @@ def test_decode_attn_auto_broadcasts_and_tiles():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
     # interpret mode: S=24 does not divide block_s=16 — a legal block is
-    # derived (the largest divisor) instead of tripping the grid check
+    # derived (the largest 8-aligned divisor) instead of tripping the grid
+    # check
     got_i = decode_attn_auto(q, k, v, jnp.full((b,), 7, jnp.int32),
                              block_s=16, interpret=True)
     np.testing.assert_allclose(np.asarray(got_i), np.asarray(want),
                                rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("n, block, align, want", [
+    (6400, 128, 128, 128),     # phi3.5 expert d_ff: lane-aligned divisor
+    (2048, 512, 8, 512),       # decode cache capacity
+    (24, 16, 8, 8),            # largest sublane-aligned divisor
+    (100, 128, 128, 100),      # whole axis fits one block
+    (2880, 128, 128, None),    # no 128-multiple <= 128 divides 2880
+])
+def test_divisor_block_is_aligned_or_raises(n, block, align, want):
+    """A block the TPU compiler would refuse (not a multiple of the tile)
+    is never picked silently: the axis fits one block, or an aligned
+    divisor exists, or the call raises."""
+    from repro.kernels.ops import _divisor_block
+
+    if want is None:
+        with pytest.raises(ValueError, match="no block"):
+            _divisor_block(n, block, align)
+    else:
+        assert _divisor_block(n, block, align) == want
 
 
 # -- engines ----------------------------------------------------------------
