@@ -143,25 +143,6 @@ METRICS = [
      lambda r: _get(r, "chaos.shed.shed.ttft_p95_steps"), False, False),
     ("chaos shed count",
      lambda r: _get(r, "chaos.shed.shed.shed"), True, False),
-] + [
-    # Trace section: the untraced leg's throughput is the pre-telemetry
-    # baseline the PR must not move — gate it. The disabled-hub ratio is a
-    # same-process paired ratio but it IS the section's headline claim
-    # (disabled telemetry is free), so it is gated too; the enabled leg
-    # pays for span records + block_until_ready by design and stays
-    # informational. The mesh leg's structural gates (dispatch_round spans
-    # present, events interleaved, token identity) live in the section's
-    # own "ok".
-    ("trace untraced tok/s",
-     lambda r: _get(r, "trace.untraced.tok_per_s"), True, True),
-    ("trace disabled/untraced ratio",
-     lambda r: _get(r, "trace.disabled_ratio"), True, True),
-    ("trace enabled tok/s",
-     lambda r: _get(r, "trace.enabled.tok_per_s"), True, False),
-    ("trace enabled/untraced ratio",
-     lambda r: _get(r, "trace.enabled_ratio"), True, False),
-    ("trace mesh dispatch_round spans",
-     lambda r: _get(r, "trace.mesh.dispatch_rounds"), True, False),
 ]
 
 
@@ -171,7 +152,7 @@ METRICS = [
 # the drift the gate exists to prevent — adding a bench section must come
 # with its METRICS entries (or an explicit KNOWN_SECTIONS listing).
 KNOWN_SECTIONS = {"admission", "chaos", "continuous", "chunked", "drift",
-                  "kernels", "multi", "overlap", "skew", "sweep", "trace"}
+                  "kernels", "multi", "overlap", "skew", "sweep"}
 
 
 def _section_rows(baseline: dict, new: dict):

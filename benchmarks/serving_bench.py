@@ -9,7 +9,6 @@ multi-tenant colocation.
   PYTHONPATH=src python -m benchmarks.serving_bench --multi     # N tenants
   PYTHONPATH=src python -m benchmarks.serving_bench --sweep     # 4 scenarios
   PYTHONPATH=src python -m benchmarks.serving_bench --chaos     # faults
-  PYTHONPATH=src python -m benchmarks.serving_bench --trace     # telemetry
   PYTHONPATH=src python -m benchmarks.serving_bench --all --json BENCH_serving.json
 
 Each section is a pass/fail experiment:
@@ -78,15 +77,6 @@ Each section is a pass/fail experiment:
   under ``EdfAdmission(shed=True)`` must reject the provably-late tail
   with typed reasons while the admitted requests' p95 TTFT stays within
   the no-overload bound and none of them starve.
-* **trace** — unified telemetry (not part of ``--all``; it has a dedicated
-  CI step). Overhead leg: the same stream through ``telemetry=None``,
-  ``Telemetry(enabled=False)`` and an enabled hub — byte-identical tokens,
-  the disabled hub within the overhead floor of untraced, and the token
-  counter exactly matching emitted tokens. Mesh leg (subprocess, 8 host
-  devices, overlap dispatch): records per-round ``dispatch_round`` spans
-  plus straggler-fault and rounds-swap adoption events, writes the JSONL +
-  Chrome-trace exports, and validates them from disk (round-trip,
-  interleaving, timeline order, token identity vs a clean run).
 
 Every section's JSON legs share one base schema (``_leg``): ``tokens``,
 ``wall_s``, ``tok_per_s``, plus section-specific extras — ``compare.py``
@@ -1613,267 +1603,6 @@ def bench_chaos(arch="phi3.5-moe-42b-a6.6b", n_devices=8, n_experts=8,
 
 
 # ---------------------------------------------------------------------------
-# Section 7: telemetry — overhead, identity, and the step-timeline trace
-# ---------------------------------------------------------------------------
-
-_TRACE_WORKER = """
-import dataclasses, json
-import numpy as np
-import jax
-from repro.configs import get_config
-from repro.core import synthetic_trace
-from repro.launch.mesh import make_ep_mesh
-from repro.models import Model
-from repro.serving import (DistributedEngine, EngineConfig, FaultInjector,
-                           FaultPlan, HealthMonitor, Request, Straggler,
-                           Telemetry, rounds_from_trace)
-
-n_dev = {n_devices}
-cfg = get_config("{arch}").reduced()
-cfg = dataclasses.replace(cfg, moe=dataclasses.replace(
-    cfg.moe, n_experts={n_experts}))
-model = Model(cfg)
-params = model.init(jax.random.PRNGKey(0))
-mesh = make_ep_mesh(n_dev)
-rounds = rounds_from_trace(
-    synthetic_trace("hist", n_experts={n_experts}, n_layers=2, seed=0),
-    n_dev)
-alt = rounds_from_trace(
-    synthetic_trace("live", n_experts={n_experts}, n_layers=2, seed=1),
-    n_dev)
-
-def stream():
-    rng = np.random.default_rng(0)
-    return [Request(prompt=[int(x) for x in rng.integers(1, cfg.vocab, 6)],
-                    max_new_tokens={max_new}, arrival=float(i))
-            for i in range({n_requests})]
-
-def drive(eng, reqs, pre=None, post=None):
-    pend = sorted(reqs, key=lambda r: r.arrival)
-    t, i, step = 0.0, 0, 0
-    while i < len(pend) or eng.queue or eng.num_active or eng.num_pending:
-        while i < len(pend) and pend[i].arrival <= t:
-            eng.submit(pend[i])
-            i += 1
-        if pre is not None:
-            pre(step)
-        busy = eng.step()
-        step += 1
-        if post is not None:
-            post(step)
-        if not busy and i < len(pend):
-            t = max(t + 1.0, pend[i].arrival)
-        else:
-            t += 1.0
-    return [r.out_tokens for r in pend]
-
-# Reference: same stream, no telemetry / injector / swap — the traced run
-# below must emit byte-identical tokens (telemetry and rounds swaps are
-# watch-only / placement-only).
-ref_eng = DistributedEngine(model, params, 2, 32, mesh=mesh,
-                            moe_impl="aurora", rounds=rounds, overlap=True,
-                            config=EngineConfig(prefill_len=8))
-out_ref = drive(ref_eng, stream())
-
-tel = Telemetry()
-health = HealthMonitor(n_devices=n_dev, straggler_ratio=2.0,
-                       min_observations=2, halflife=4.0, telemetry=tel)
-inj = FaultInjector(FaultPlan(faults=(Straggler(step={straggle_step},
-                                                device=1, factor=16.0),),
-                              name="trace"),
-                    n_devices=n_dev, health=health)
-eng = DistributedEngine(model, params, 2, 32, mesh=mesh,
-                        moe_impl="aurora", rounds=rounds, overlap=True,
-                        config=EngineConfig(prefill_len=8,
-                                            step_wrapper=inj.wrap,
-                                            telemetry=tel))
-inj.attach(eng)
-swapped = [False]
-
-def post(step):
-    health.check(step)                       # straggler -> fault event
-    if step >= {swap_step} and not swapped[0]:
-        swapped[0] = True
-        eng.swap_rounds(alt)                 # -> adoption event
-
-out = drive(eng, stream(), pre=lambda s: inj.tick(), post=post)
-
-out_base = "{out_base}"
-tel.write_jsonl(out_base + ".jsonl")
-tel.write_chrome_trace(out_base + ".trace.json")
-
-# Validate the exports by reading them BACK from disk: every line of the
-# JSONL and the whole Chrome trace must round-trip json.loads.
-recs = [json.loads(ln) for ln in open(out_base + ".jsonl")]
-trace = json.load(open(out_base + ".trace.json"))
-spans = [r for r in recs if r["type"] == "span"]
-dispatch = [r for r in spans if r["name"] == "dispatch_round"]
-evs = [r for r in recs if r["type"] == "event"]
-faults = [e for e in evs if e["kind"] in ("fault", "fault_injected")]
-adoptions = [e for e in evs if e["kind"] == "adoption"]
-span_lo = min(s["ts"] for s in spans)
-span_hi = max(s["ts"] + s["dur"] for s in spans)
-interleaved = all(span_lo <= e["ts"] <= span_hi
-                  for e in faults + adoptions)
-ordered = all(recs[i]["ts"] <= recs[i + 1]["ts"]
-              for i in range(len(recs) - 1))
-rec = {{
-    "n_devices": n_dev, "n_experts": {n_experts},
-    "records": len(recs), "spans": len(spans),
-    "dispatch_rounds": len(dispatch),
-    "fault_events": len(faults), "adoptions": len(adoptions),
-    "chrome_events": len(trace["traceEvents"]),
-    "interleaved": interleaved, "ordered": ordered,
-    "identical": out == out_ref,
-    "files": [out_base + ".jsonl", out_base + ".trace.json"],
-}}
-rec["ok"] = bool(
-    rec["dispatch_rounds"] >= 1 and rec["fault_events"] >= 1
-    and rec["adoptions"] >= 1 and rec["interleaved"] and rec["ordered"]
-    and rec["identical"] and rec["chrome_events"] >= rec["records"])
-rec["platform"] = jax.devices()[0].platform   # always "cpu"
-print("TRACE_JSON " + json.dumps(rec))
-"""
-
-
-def bench_trace(arch="qwen3-32b", mesh_arch="phi3.5-moe-42b-a6.6b",
-                n_requests=12, batch_slots=4, prompt_len=8, max_new=16,
-                rate=1.0, cache_cap=48, overhead_floor=0.98, seed=0,
-                repeats=5, n_devices=8, n_experts=8, mesh_requests=6,
-                mesh_max_new=4, straggle_step=2, swap_step=5,
-                out_base="BENCH_trace_worker"):
-    """Telemetry: zero overhead when off, token identity, and the timeline.
-
-    Two legs:
-
-    * **overhead** (main process): the SAME Poisson stream through three
-      otherwise-identical engines — ``telemetry=None`` (the pre-telemetry
-      code path, no wrapper composed), ``Telemetry(enabled=False)`` (the
-      runtime off-switch), and an enabled hub. Gates: all three emit
-      byte-identical tokens (telemetry only watches), the disabled leg's
-      throughput stays within ``1 - overhead_floor`` of untraced (median
-      of interleaved paired reps), and the enabled hub's
-      ``serving_tokens_total`` counter agrees exactly with the tokens the
-      stream actually emitted. The enabled leg's ratio is reported for
-      the CI trend table (it pays for span records + ``block_until_ready``
-      per step — honesty, not a regression).
-    * **mesh** (subprocess, ``n_devices``-way host-device EP mesh): one
-      stream through a round-pipelined ``--overlap``-style
-      ``DistributedEngine`` with an enabled hub, a synthetic straggler
-      (fault event via ``HealthMonitor``) and a mid-stream
-      ``swap_rounds`` (adoption event). The worker writes the JSONL and
-      Chrome-trace files and validates them FROM DISK: every record
-      round-trips ``json.loads``, per-round ``dispatch_round`` spans are
-      present, fault + adoption events interleave inside the span
-      timeline in ``ts`` order, and tokens match a clean reference run.
-    """
-    from repro.serving import (ContinuousEngine, EngineConfig, Telemetry,
-                               poisson_requests)
-
-    # -- mesh timeline leg (subprocess: needs its own device mesh) ---------
-    script = _TRACE_WORKER.format(
-        arch=mesh_arch, n_devices=n_devices, n_experts=n_experts,
-        n_requests=mesh_requests, max_new=mesh_max_new,
-        straggle_step=straggle_step, swap_step=swap_step, out_base=out_base)
-    mesh_rec, err = _run_worker(script, _worker_env(n_devices), "trace",
-                                "TRACE_JSON ", timeout=1200, retries=1)
-    if mesh_rec is None:
-        mesh_rec = {"ok": False, "error": err}
-    else:
-        print(f"== trace mesh leg: {n_experts} experts EP-sharded over "
-              f"{n_devices} host devices, overlap dispatch, straggler @ "
-              f"step {straggle_step}, rounds swap @ step {swap_step} ==")
-        print(f"{mesh_rec['records']} records ({mesh_rec['spans']} spans, "
-              f"{mesh_rec['dispatch_rounds']} dispatch_round, "
-              f"{mesh_rec['fault_events']} fault + "
-              f"{mesh_rec['adoptions']} adoption events), "
-              f"{mesh_rec['chrome_events']} Chrome trace events")
-        print("events interleave in timeline order; tokens identical to "
-              "the untraced reference" if mesh_rec["ok"] else
-              "FAIL: trace timeline gates not met")
-
-    # -- overhead + identity leg (main process) ----------------------------
-    cfg, model, params = _build(arch)
-    rng = np.random.default_rng(seed)
-    stream = poisson_requests(rng, n_requests, rate, cfg.vocab, prompt_len,
-                              max_new_lo=max_new // 2, max_new_hi=max_new)
-    tel = Telemetry()
-    engines = {
-        "untraced": ContinuousEngine(
-            model, params, batch_slots, cache_cap,
-            config=EngineConfig(prefill_len=prompt_len)),
-        "disabled": ContinuousEngine(
-            model, params, batch_slots, cache_cap,
-            config=EngineConfig(prefill_len=prompt_len,
-                                telemetry=Telemetry(enabled=False))),
-        "enabled": ContinuousEngine(
-            model, params, batch_slots, cache_cap,
-            config=EngineConfig(prefill_len=prompt_len, telemetry=tel)),
-    }
-    for eng in engines.values():
-        eng.serve(_clone(stream))                   # warm-up compiles
-    tok_counter = tel.metrics["serving_tokens_total"]
-    counted0 = tok_counter.value(tenant="")
-    runs = {name: [] for name in engines}
-    outs = {}
-    for _ in range(repeats):
-        for name, eng in engines.items():           # interleaved pairs
-            final = _clone(stream)
-            t0 = time.perf_counter()
-            eng.serve(final)
-            wall = time.perf_counter() - t0
-            runs[name].append((sum(len(r.out_tokens) for r in final), wall))
-            outs[name] = [r.out_tokens for r in final]
-    assert outs["untraced"] == outs["disabled"] == outs["enabled"], \
-        "telemetry changed emitted tokens (watch-only violated)"
-
-    tokens = runs["untraced"][-1][0]
-    counted = tok_counter.value(tenant="") - counted0
-    tokens_counted_ok = counted == tokens * repeats
-    results = {}
-    for name, reps in runs.items():
-        results[name] = _leg(reps[-1][0],
-                             float(np.median([w for _, w in reps])))
-        results[name]["tok_per_s"] = float(
-            np.median([t / w for t, w in reps]))
-    ratios = {
-        name: float(np.median(
-            [(runs[name][i][0] / runs[name][i][1])
-             / (runs["untraced"][i][0] / runs["untraced"][i][1])
-             for i in range(repeats)]))
-        for name in ("disabled", "enabled")}
-
-    print(f"== trace overhead leg: {arch} (reduced), {n_requests} requests, "
-          f"{batch_slots} slots, {repeats} interleaved reps ==")
-    print(f"{'leg':<10} {'tokens':>7} {'wall s':>8} {'tok/s':>9} "
-          f"{'vs untraced':>12}")
-    for name in ("untraced", "disabled", "enabled"):
-        r = results[name]
-        ratio = ratios.get(name)
-        print(f"{name:<10} {r['tokens']:>7} {r['wall_s']:>8.2f} "
-              f"{r['tok_per_s']:>9.1f} "
-              f"{'-' if ratio is None else format(ratio, '11.2f') + 'x':>12}")
-    print(f"disabled hub costs {(1 - ratios['disabled']) * 100:+.1f}% "
-          f"(floor {overhead_floor:g}); tokens identical across legs; "
-          f"serving_tokens_total counted {counted:g} "
-          f"(expected {tokens * repeats})")
-    ok = bool(ratios["disabled"] >= overhead_floor and tokens_counted_ok
-              and mesh_rec.get("ok"))
-    return {
-        "arch": arch, "n_requests": n_requests,
-        "untraced": results["untraced"], "disabled": results["disabled"],
-        "enabled": results["enabled"],
-        "disabled_ratio": ratios["disabled"],
-        "enabled_ratio": ratios["enabled"],
-        "overhead_floor": overhead_floor,
-        "tokens_counted_ok": bool(tokens_counted_ok),
-        "spans_recorded": len(tel.spans),
-        "events_published": sum(tel.bus.counts.values()),
-        "mesh": mesh_rec, "ok": ok,
-    }
-
-
-# ---------------------------------------------------------------------------
 # Driver
 # ---------------------------------------------------------------------------
 
@@ -1913,12 +1642,6 @@ def main() -> int:
                          "failover (subprocess mesh) and shed-mode EDF "
                          "under an overload burst; not part of --all — it "
                          "has its own CI step")
-    ap.add_argument("--trace", action="store_true",
-                    help="run the telemetry section: disabled-hub overhead "
-                         "+ token identity in-process, and a subprocess "
-                         "mesh leg that records and validates the JSONL / "
-                         "Chrome-trace step timeline; not part of --all — "
-                         "it has its own CI step")
     ap.add_argument("--all", action="store_true",
                     help="run every section (except --sweep and --chaos)")
     ap.add_argument("--small", action="store_true",
@@ -1933,8 +1656,7 @@ def main() -> int:
     run_classic = args.all or not (args.chunked or args.drift or args.multi
                                    or args.kernels or args.overlap
                                    or args.skew or args.admission
-                                   or args.sweep or args.chaos
-                                   or args.trace)
+                                   or args.sweep or args.chaos)
     run_chunked = args.all or args.chunked or args.drift
     run_admission = args.all or args.admission
     run_drift = args.all or args.drift
@@ -2009,16 +1731,6 @@ def main() -> int:
               if args.small else {})
         sections["chaos"] = bench_chaos(arch=args.moe_arch, seed=args.seed,
                                         **kw)
-    if args.trace:
-        # Deliberately outside --all (like --sweep/--chaos): the mesh leg
-        # spawns an 8-device subprocess and the overhead metric gets its
-        # own baseline-gated CI step.
-        kw = (dict(n_requests=8, max_new=10, repeats=3, mesh_requests=5)
-              if args.small else {})
-        sections["trace"] = bench_trace(arch=args.arch,
-                                        mesh_arch=args.moe_arch,
-                                        seed=args.seed, **kw)
-
     if args.json:
         with open(args.json, "w") as f:
             json.dump(sections, f, indent=2)

@@ -207,33 +207,35 @@ def _scatter_buckets(xt, valid, router_w, moe, token_axes, spec=None):
 
     t_loc, d = xt.shape
     e = moe.n_experts
-    gates, idx, aux = route(router_w, xt, moe)
-    aux = jax.lax.pmean(aux, token_axes)
-    cap = capacity(t_loc, moe.top_k, e, moe.capacity_factor)
-    slot, keep = dispatch_indices(idx, e, cap)
-    keep = keep & valid[:, None]
-
-    # Scatter local tokens into per-(expert) capacity buckets: (E', C, d).
-    tok_ids = jnp.broadcast_to(jnp.arange(t_loc)[:, None], idx.shape)
-    e_f, s_f, t_f = idx.reshape(-1), slot.reshape(-1), tok_ids.reshape(-1)
-    k_f = keep.reshape(-1)
-    if spec is not None:
-        base, reps = replica_arrays(spec)
-        r_f = reps[e_f]
-        e_f = base[e_f] + s_f % r_f
-        s_f = s_f // r_f
-        n_phys = spec.n_phys
-    else:
-        n_phys = e
-    safe_s = jnp.where(k_f, s_f, cap - 1)
-    buf = jnp.zeros((n_phys, cap, d), xt.dtype)
-    buf = buf.at[e_f, safe_s].add(jnp.where(k_f[:, None], xt[t_f], 0.0))
+    with jax.named_scope("moe/router"):
+        gates, idx, aux = route(router_w, xt, moe)
+        aux = jax.lax.pmean(aux, token_axes)
+    with jax.named_scope("moe/dispatch"):
+        cap = capacity(t_loc, moe.top_k, e, moe.capacity_factor)
+        slot, keep = dispatch_indices(idx, e, cap)
+        keep = keep & valid[:, None]
+        # Scatter local tokens into per-(expert) capacity buckets.
+        tok_ids = jnp.broadcast_to(jnp.arange(t_loc)[:, None], idx.shape)
+        e_f, s_f, t_f = idx.reshape(-1), slot.reshape(-1), tok_ids.reshape(-1)
+        k_f = keep.reshape(-1)
+        if spec is not None:
+            base, reps = replica_arrays(spec)
+            r_f = reps[e_f]
+            e_f = base[e_f] + s_f % r_f
+            s_f = s_f // r_f
+            n_phys = spec.n_phys
+        else:
+            n_phys = e
+        safe_s = jnp.where(k_f, s_f, cap - 1)
+        buf = jnp.zeros((n_phys, cap, d), xt.dtype)
+        buf = buf.at[e_f, safe_s].add(jnp.where(k_f[:, None], xt[t_f], 0.0))
 
     def combine(back):
-        picked = back[e_f, safe_s]
-        picked = jnp.where(k_f[:, None], picked, 0.0)
-        return jnp.zeros_like(xt).at[t_f].add(
-            picked * gates.reshape(-1)[:, None])
+        with jax.named_scope("moe/combine"):
+            picked = back[e_f, safe_s]
+            picked = jnp.where(k_f[:, None], picked, 0.0)
+            return jnp.zeros_like(xt).at[t_f].add(
+                picked * gates.reshape(-1)[:, None])
 
     return buf, combine, aux, idx
 
@@ -248,15 +250,17 @@ def _replicated_counts(idx, valid, n_experts: int, token_axes):
     histogram, exactly matching the local paths' output frame."""
     from repro.models.moe import routed_counts
 
-    cnt = routed_counts(idx, n_experts) * valid[:, None].astype(jnp.float32)
-    t_loc = cnt.shape[0]
-    n_shards = 1
-    for ax in token_axes:
-        n_shards *= jax.lax.axis_size(ax)
-    shard = flat_axis_index(token_axes)
-    full = jnp.zeros((n_shards * t_loc, n_experts), jnp.float32)
-    full = jax.lax.dynamic_update_slice(full, cnt, (shard * t_loc, 0))
-    return jax.lax.psum(full, tuple(token_axes))
+    with jax.named_scope("moe/router"):
+        cnt = routed_counts(idx, n_experts) * valid[:, None].astype(
+            jnp.float32)
+        t_loc = cnt.shape[0]
+        n_shards = 1
+        for ax in token_axes:
+            n_shards *= jax.lax.axis_size(ax)
+        shard = flat_axis_index(token_axes)
+        full = jnp.zeros((n_shards * t_loc, n_experts), jnp.float32)
+        full = jax.lax.dynamic_update_slice(full, cnt, (shard * t_loc, 0))
+        return jax.lax.psum(full, tuple(token_axes))
 
 
 def _local_dispatch_combine(xt, valid, router_w, experts, moe, act,
@@ -275,20 +279,23 @@ def _local_dispatch_combine(xt, valid, router_w, experts, moe, act,
     epd = n_phys // n_ep                             # experts per device
 
     # First all-to-all (token dispatch, D_N).
-    buf = buf.reshape(n_ep, epd, cap, d)
-    recv = ep_all_to_all(buf, ep_axes, rounds)       # (n_src, epd, C, d)
-    recv = recv.transpose(1, 0, 2, 3).reshape(epd, n_ep * cap, d)
+    with jax.named_scope("moe/exchange"):
+        buf = buf.reshape(n_ep, epd, cap, d)
+        recv = ep_all_to_all(buf, ep_axes, rounds)   # (n_src, epd, C, d)
+        recv = recv.transpose(1, 0, 2, 3).reshape(epd, n_ep * cap, d)
 
     # Expert FFN on this device's experts.
     from repro.models.layers import ffn_apply
-    out = jax.vmap(lambda p, xb: ffn_apply(p, xb, act))(experts, recv)
+    with jax.named_scope("moe/experts"):
+        out = jax.vmap(lambda p, xb: ffn_apply(p, xb, act))(experts, recv)
 
     # Second all-to-all (expert-output return, D_C = D_N^T): same rounds —
     # the two phases are exact reverses (§2.2), so the contention-free
     # property carries over by symmetry.
-    out = out.reshape(epd, n_ep, cap, d).transpose(1, 0, 2, 3)
-    back = ep_all_to_all(out, ep_axes, rounds)       # (E_dev_of_pair …)
-    back = back.reshape(n_phys, cap, d)
+    with jax.named_scope("moe/exchange"):
+        out = out.reshape(epd, n_ep, cap, d).transpose(1, 0, 2, 3)
+        back = ep_all_to_all(out, ep_axes, rounds)   # (E_dev_of_pair …)
+        back = back.reshape(n_phys, cap, d)
 
     y = combine(back)
     if return_counts:

@@ -69,7 +69,9 @@ def pipelined_local_dispatch_combine(xt, valid, router_w, experts, moe, act,
     buf = buf.reshape(n_ep, epd, cap, d)             # buf[s] → device s
 
     def experts_ffn(chunk):                          # (epd, C, d)
-        return jax.vmap(lambda p, xb: ffn_apply(p, xb, act))(experts, chunk)
+        with jax.named_scope("moe/experts"):
+            return jax.vmap(lambda p, xb: ffn_apply(p, xb, act))(experts,
+                                                                  chunk)
 
     # out[s] = FFN outputs of MY tokens processed on device s's experts;
     # row n_ep is a scratch slot for rounds where this device is idle.
@@ -81,11 +83,12 @@ def pipelined_local_dispatch_combine(xt, valid, router_w, experts, moe, act,
         chunk). Issued AFTER the next round's forward ppermute, so both the
         FFN and the return transfer sit in that round's latency window."""
         y = experts_ffn(chunk)
-        if back_perm is None:                        # self chunk: no network
-            return jax.lax.dynamic_update_index_in_dim(out, y, me, 0)
-        back = jax.lax.ppermute(y, axis_name, back_perm)
-        w = jnp.asarray(write_tbl)[me]
-        return jax.lax.dynamic_update_index_in_dim(out, back, w, 0)
+        with jax.named_scope("moe/exchange"):
+            if back_perm is None:                    # self chunk: no network
+                return jax.lax.dynamic_update_index_in_dim(out, y, me, 0)
+            back = jax.lax.ppermute(y, axis_name, back_perm)
+            w = jnp.asarray(write_tbl)[me]
+            return jax.lax.dynamic_update_index_in_dim(out, back, w, 0)
 
     # Prologue: the self chunk "arrived" before any round; its FFN fills the
     # first round's latency window (self-traffic never crosses the network).
@@ -94,9 +97,11 @@ def pipelined_local_dispatch_combine(xt, valid, router_w, experts, moe, act,
     for dst_vec in rounds:
         dst = np.asarray(dst_vec)
         perm = [(i, int(j)) for i, j in enumerate(dst) if j >= 0]
-        send_idx = jnp.asarray(np.where(dst < 0, 0, dst))[me]
-        send = jax.lax.dynamic_index_in_dim(buf, send_idx, 0, keepdims=False)
-        recv = jax.lax.ppermute(send, axis_name, perm)   # round in flight…
+        with jax.named_scope("moe/exchange"):
+            send_idx = jnp.asarray(np.where(dst < 0, 0, dst))[me]
+            send = jax.lax.dynamic_index_in_dim(buf, send_idx, 0,
+                                                keepdims=False)
+            recv = jax.lax.ppermute(send, axis_name, perm)  # in flight…
         out = flush(out, *pending)                       # …compute ≤ r
         # The chunk just received returns through the transposed permutation
         # and lands in my out row for the device I sent to this round.

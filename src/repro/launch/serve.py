@@ -35,8 +35,9 @@ N — use ``--experts`` to widen the reduced configs.
 
 ``--trace-out BASE`` / ``--metrics-out PATH`` attach the unified telemetry
 hub (serving/telemetry.py) to whichever engine is built: structured spans
-(engine_step > prefill_chunk / decode_step > dispatch_round) and the typed
-event bus (replan / shed / fault / adoption) land in ``BASE.jsonl`` and
+(engine_step > admit / prefill_chunk / decode_step / sample / readback /
+emit) and the typed event bus (replan / shed / fault / adoption) land in
+``BASE.jsonl`` and
 ``BASE.trace.json`` (Chrome trace-event JSON — open in Perfetto), and the
 final metrics snapshot (tok/s, TTFT, expert-load imbalance, …) is written
 as JSON on exit — including on Ctrl-C.

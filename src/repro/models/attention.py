@@ -7,6 +7,10 @@ buffer (RoPE is applied at write time with absolute positions, so ring slots
 need no re-rotation). MLA caches the **compressed latent** (kv_lora + rope
 key) and decodes with the absorbed-matrix form — the memory win that makes
 DeepSeek-V3 decode feasible.
+
+Every cache write runs under ``jax.named_scope("cache_write")``; the
+transformer stack calls these blocks under ``attn``, so a device trace
+names the writes ``attn/cache_write``.
 """
 
 from __future__ import annotations
@@ -59,18 +63,19 @@ def _cache_write(cache_arr, new, slot, pc):
     elementwise, stays local to each shard, and decode streams the full
     cache for attention anyway (§Perf iteration 5).
     """
-    cap = cache_arr.shape[1]
-    slot = jnp.asarray(slot)
-    if slot.ndim == 1:
-        mask = (jnp.arange(cap)[None, :] == slot[:, None]).reshape(
-            (slot.shape[0], cap) + (1,) * (cache_arr.ndim - 2))
+    with jax.named_scope("cache_write"):
+        cap = cache_arr.shape[1]
+        slot = jnp.asarray(slot)
+        if slot.ndim == 1:
+            mask = (jnp.arange(cap)[None, :] == slot[:, None]).reshape(
+                (slot.shape[0], cap) + (1,) * (cache_arr.ndim - 2))
+            return jnp.where(mask, new.astype(cache_arr.dtype), cache_arr)
+        if pc is None or pc.mesh is None:
+            idx = (0, slot) + (0,) * (cache_arr.ndim - 2)
+            return jax.lax.dynamic_update_slice(cache_arr, new, idx)
+        mask = (jnp.arange(cap) == slot).reshape(
+            (1, cap) + (1,) * (cache_arr.ndim - 2))
         return jnp.where(mask, new.astype(cache_arr.dtype), cache_arr)
-    if pc is None or pc.mesh is None:
-        idx = (0, slot) + (0,) * (cache_arr.ndim - 2)
-        return jax.lax.dynamic_update_slice(cache_arr, new, idx)
-    mask = (jnp.arange(cap) == slot).reshape(
-        (1, cap) + (1,) * (cache_arr.ndim - 2))
-    return jnp.where(mask, new.astype(cache_arr.dtype), cache_arr)
 
 
 def _decode_attn_kernel(q, k, v, valid, pc):
@@ -172,19 +177,21 @@ def attn_block(p, x, *, cfg, pos, window=None, cache=None, length=None,
         cap = cache["k"].shape[1]
         out = attention_core(q, k, v, causal_offset=offset, window=window,
                              valid_len=None, flash_block=flash_block)
-        if cap < s:
-            # Ring buffer smaller than the prefill: keep the last cap tokens
-            # (their slot indices are consecutive mod cap → unique writes).
-            kk, vv = k[:, s - cap:], v[:, s - cap:]
-            slots = pos[0, s - cap:] % cap
-            new_cache = {"k": cache["k"].at[:, slots].set(kk),
-                         "v": cache["v"].at[:, slots].set(vv)}
-        else:
-            new_cache = {
-                "k": jax.lax.dynamic_update_slice(
-                    cache["k"], k, (0, 0, 0, 0)),
-                "v": jax.lax.dynamic_update_slice(
-                    cache["v"], v, (0, 0, 0, 0))}
+        with jax.named_scope("cache_write"):
+            if cap < s:
+                # Ring buffer smaller than the prefill: keep the last cap
+                # tokens (their slot indices are consecutive mod cap →
+                # unique writes).
+                kk, vv = k[:, s - cap:], v[:, s - cap:]
+                slots = pos[0, s - cap:] % cap
+                new_cache = {"k": cache["k"].at[:, slots].set(kk),
+                             "v": cache["v"].at[:, slots].set(vv)}
+            else:
+                new_cache = {
+                    "k": jax.lax.dynamic_update_slice(
+                        cache["k"], k, (0, 0, 0, 0)),
+                    "v": jax.lax.dynamic_update_slice(
+                        cache["v"], v, (0, 0, 0, 0))}
     elif mode == "prefill":
         # Chunked CONTINUATION: the chunk's keys land at the current fill
         # level ``length`` and queries attend the cached prefix plus the
@@ -200,18 +207,19 @@ def attn_block(p, x, *, cfg, pos, window=None, cache=None, length=None,
             raise ValueError("chunked prefill continuation into a cache "
                              f"smaller than the chunk ({cap} < {s})")
         start = length.astype(jnp.int32)
-        if start.ndim == 1:
-            rows = jnp.arange(b)[:, None]
-            idx = start[:, None] + jnp.arange(s)[None]       # (B, s)
-            ck = cache["k"].at[rows, idx].set(
-                k.astype(cache["k"].dtype), mode="drop")
-            cv = cache["v"].at[rows, idx].set(
-                v.astype(cache["v"].dtype), mode="drop")
-        else:
-            ck = jax.lax.dynamic_update_slice(
-                cache["k"], k.astype(cache["k"].dtype), (0, start, 0, 0))
-            cv = jax.lax.dynamic_update_slice(
-                cache["v"], v.astype(cache["v"].dtype), (0, start, 0, 0))
+        with jax.named_scope("cache_write"):
+            if start.ndim == 1:
+                rows = jnp.arange(b)[:, None]
+                idx = start[:, None] + jnp.arange(s)[None]   # (B, s)
+                ck = cache["k"].at[rows, idx].set(
+                    k.astype(cache["k"].dtype), mode="drop")
+                cv = cache["v"].at[rows, idx].set(
+                    v.astype(cache["v"].dtype), mode="drop")
+            else:
+                ck = jax.lax.dynamic_update_slice(
+                    cache["k"], k.astype(cache["k"].dtype), (0, start, 0, 0))
+                cv = jax.lax.dynamic_update_slice(
+                    cache["v"], v.astype(cache["v"].dtype), (0, start, 0, 0))
         out = attention_core(q, ck, cv, causal_offset=start,
                              window=window, valid_len=start + s,
                              flash_block=flash_block)
@@ -310,11 +318,12 @@ def mla_block(p, x, *, cfg, pos, cache=None, length=None, mode="train",
                              valid_len=None, flash_block=flash_block)
         out = out[..., :m.v_head_dim]
         if mode == "prefill":
-            new_cache = {
-                "ckv": jax.lax.dynamic_update_slice(
-                    cache["ckv"], ckv, (0, 0, 0)),
-                "k_rope": jax.lax.dynamic_update_slice(
-                    cache["k_rope"], k_rope, (0, 0, 0))}
+            with jax.named_scope("cache_write"):
+                new_cache = {
+                    "ckv": jax.lax.dynamic_update_slice(
+                        cache["ckv"], ckv, (0, 0, 0)),
+                    "k_rope": jax.lax.dynamic_update_slice(
+                        cache["k_rope"], k_rope, (0, 0, 0))}
     else:  # decode — absorbed-matrix form over the latent cache
         cap = cache["ckv"].shape[1]
         slot = jnp.minimum(length, cap - 1)

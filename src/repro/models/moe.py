@@ -17,6 +17,11 @@ Four dispatch implementations share the same routing/capacity semantics:
                ``lax.ppermute`` permutation rounds (Thm 4.2 / BvN), computed
                host-side by ``repro.core.schedule`` from historical traffic.
 
+Every path names its parts with ``jax.named_scope`` — ``moe/router``,
+``moe/dispatch`` (capacity ranks and the bucket scatter), ``moe/experts``
+(the expert FFNs), ``moe/exchange`` (the collectives, on a mesh) and
+``moe/combine`` — so a device trace attributes each op to one of them.
+
 Routing follows the assigned architectures: softmax top-k (phi3.5-moe) and
 DeepSeek-V3 sigmoid scoring with normalized top-k gates, an optional shared
 expert, and leading dense layers. The Switch-style load-balance auxiliary loss
@@ -345,41 +350,56 @@ def moe_apply_dense(p, x, moe, act: str,
     d = shape[-1]
     xt = x.reshape(-1, d)                                # (T, d)
     t = xt.shape[0]
-    gates, idx, aux = route(p["router"], xt, moe)
-    cap = capacity(t, moe.top_k, moe.n_experts, moe.capacity_factor)
-    slot, keep = dispatch_indices(idx, moe.n_experts, cap)
+    with jax.named_scope("moe/router"):
+        gates, idx, aux = route(p["router"], xt, moe)
 
-    # Scatter tokens into (E, C, d) buckets. Under replication the routing
-    # above ran in the LOGICAL frame (same capacity, same drops); only the
-    # bucket coordinates move: rank r of expert e lands on replica r % r_e
-    # at position r // r_e (collision-free, never adds drops).
-    tok_ids = jnp.broadcast_to(jnp.arange(t)[:, None], idx.shape)
-    e_f, s_f, t_f = idx.reshape(-1), slot.reshape(-1), tok_ids.reshape(-1)
-    spec = pc.moe_replication
-    if spec is not None:
-        base, reps = replica_arrays(spec)
-        r_f = reps[e_f]
-        e_f = base[e_f] + s_f % r_f
-        s_f = s_f // r_f
-        n_phys = spec.n_phys
-    else:
-        n_phys = moe.n_experts
-    buf = jnp.zeros((n_phys, cap, d), xt.dtype)
-    safe_s = jnp.where(keep.reshape(-1), s_f, cap - 1)
-    contrib = jnp.where(keep.reshape(-1)[:, None], xt[t_f], 0.0)
-    buf = buf.at[e_f, safe_s].add(contrib)  # each kept slot hit exactly once
+    with jax.named_scope("moe/dispatch"):
+        cap = capacity(t, moe.top_k, moe.n_experts, moe.capacity_factor)
+        slot, keep = dispatch_indices(idx, moe.n_experts, cap)
+        # Scatter tokens into (E, C, d) buckets. Under replication the
+        # routing above ran in the LOGICAL frame (same capacity, same
+        # drops); only the bucket coordinates move: rank r of expert e
+        # lands on replica r % r_e at position r // r_e (collision-free,
+        # never adds drops).
+        tok_ids = jnp.broadcast_to(jnp.arange(t)[:, None], idx.shape)
+        e_f, s_f, t_f = idx.reshape(-1), slot.reshape(-1), tok_ids.reshape(-1)
+        spec = pc.moe_replication
+        if spec is not None:
+            base, reps = replica_arrays(spec)
+            r_f = reps[e_f]
+            e_f = base[e_f] + s_f % r_f
+            s_f = s_f // r_f
+            n_phys = spec.n_phys
+        else:
+            n_phys = moe.n_experts
+        buf = jnp.zeros((n_phys, cap, d), xt.dtype)
+        safe_s = jnp.where(keep.reshape(-1), s_f, cap - 1)
+        contrib = jnp.where(keep.reshape(-1)[:, None], xt[t_f], 0.0)
+        buf = buf.at[e_f, safe_s].add(contrib)  # each kept slot hit once
 
-    out_buf = _experts_ffn(p["experts"], buf, act)       # (E', C, d)
+    with jax.named_scope("moe/experts"):
+        out_buf = _experts_ffn(p["experts"], buf, act)   # (E', C, d)
 
-    # Gather back and combine with gates.
-    picked = out_buf[e_f, safe_s]                        # (T*k, d)
-    picked = jnp.where(keep.reshape(-1)[:, None], picked, 0.0)
-    y = jnp.zeros_like(xt).at[t_f].add(
-        picked * gates.reshape(-1)[:, None])
+    with jax.named_scope("moe/combine"):
+        # Gather back and combine with gates.
+        picked = out_buf[e_f, safe_s]                    # (T*k, d)
+        picked = jnp.where(keep.reshape(-1)[:, None], picked, 0.0)
+        y = jnp.zeros_like(xt).at[t_f].add(
+            picked * gates.reshape(-1)[:, None])
+    return _shared_and_counts(p, xt, y, idx, shape, aux, moe, act, pc,
+                              return_counts)
+
+
+def _shared_and_counts(p, xt, y, idx, shape, aux, moe, act, pc,
+                       return_counts: bool):
+    """Common tail of every MoE path: add the shared expert (an expert FFN
+    every token takes) and, when asked, the routed-choice histogram."""
     if "shared" in p:
-        y = y + ffn_apply(p["shared"], xt, act, pc)
+        with jax.named_scope("moe/experts"):
+            y = y + ffn_apply(p["shared"], xt, act, pc)
     if return_counts:
-        counts = routed_counts(idx, moe.n_experts)               # (T, E)
+        with jax.named_scope("moe/router"):
+            counts = routed_counts(idx, moe.n_experts)   # (T, E)
         return (y.reshape(shape), aux,
                 counts.reshape(shape[:-1] + (moe.n_experts,)))
     return y.reshape(shape), aux
@@ -419,87 +439,93 @@ def moe_apply_kernel(p, x, moe, act: str,
     xt = x.reshape(-1, d)                                # (T, d)
     t = xt.shape[0]
     k, e = moe.top_k, moe.n_experts
-    gates, idx, aux = route(p["router"], xt, moe)
-    cap = capacity(t, k, e, moe.capacity_factor)
     kc = pc.kernels or KernelConfig()
-
-    order, sizes, slot, keep = sort_dispatch(idx, e, cap)
-    keep_f = keep.reshape(-1)
-    e_f = idx.reshape(-1)
-    t_f = jnp.broadcast_to(jnp.arange(t)[:, None], (t, k)).reshape(-1)
     experts = p["experts"]
+    with jax.named_scope("moe/router"):
+        gates, idx, aux = route(p["router"], xt, moe)
 
-    # Replication: routing/capacity ran in the LOGICAL frame above; remap
-    # each kept rank to (replica r % r_e, position r // r_e). ``home`` keeps
-    # the compact path exact — every replica is a byte-copy of its home.
-    spec = pc.moe_replication
-    if spec is not None:
-        base, reps = replica_arrays(spec)
-        s_f = slot.reshape(-1)
-        pe_f = base[e_f] + s_f % reps[e_f]               # physical expert
-        ps_f = s_f // reps[e_f]                          # physical position
-        home_f = base[e_f]
-        n_phys = spec.n_phys
-    else:
-        pe_f, ps_f, home_f = e_f, slot.reshape(-1), e_f
-        n_phys = e
+    with jax.named_scope("moe/dispatch"):
+        cap = capacity(t, k, e, moe.capacity_factor)
+        order, sizes, slot, keep = sort_dispatch(idx, e, cap)
+        keep_f = keep.reshape(-1)
+        e_f = idx.reshape(-1)
+        t_f = jnp.broadcast_to(jnp.arange(t)[:, None], (t, k)).reshape(-1)
+        # Replication: routing/capacity ran in the LOGICAL frame above;
+        # remap each kept rank to (replica r % r_e, position r // r_e).
+        # ``home`` keeps the compact path exact — every replica is a
+        # byte-copy of its home.
+        spec = pc.moe_replication
+        if spec is not None:
+            base, reps = replica_arrays(spec)
+            s_f = slot.reshape(-1)
+            pe_f = base[e_f] + s_f % reps[e_f]           # physical expert
+            ps_f = s_f // reps[e_f]                      # physical position
+            home_f = base[e_f]
+            n_phys = spec.n_phys
+        else:
+            pe_f, ps_f, home_f = e_f, slot.reshape(-1), e_f
+            n_phys = e
 
     compact = not kops.use_pallas(kc.interpret) and 2 * t * k <= e * cap
     if compact:
         # Decode-sized: gather each routed row's expert weights and run a
         # batched matvec over the compact (T·k, d) layout.
-        xg = xt[t_f]                                     # (T*k, d)
-        hg = jnp.einsum("rd,rdf->rf", xg, experts["w_gate"][home_f],
-                        preferred_element_type=jnp.float32)
-        hu = jnp.einsum("rd,rdf->rf", xg, experts["w_up"][home_f],
-                        preferred_element_type=jnp.float32)
-        act_fn = jax.nn.gelu if act == "geglu" else jax.nn.silu
-        h = (act_fn(hg) * hu).astype(xt.dtype)
-        picked = jnp.einsum("rf,rfd->rd", h, experts["w_down"][home_f],
-                            preferred_element_type=jnp.float32
-                            ).astype(xt.dtype)           # (T*k, d)
+        with jax.named_scope("moe/dispatch"):
+            xg = xt[t_f]                                 # (T*k, d)
+        with jax.named_scope("moe/experts"):
+            hg = jnp.einsum("rd,rdf->rf", xg, experts["w_gate"][home_f],
+                            preferred_element_type=jnp.float32)
+            hu = jnp.einsum("rd,rdf->rf", xg, experts["w_up"][home_f],
+                            preferred_element_type=jnp.float32)
+            act_fn = jax.nn.gelu if act == "geglu" else jax.nn.silu
+            h = (act_fn(hg) * hu).astype(xt.dtype)
+            picked = jnp.einsum("rf,rfd->rd", h, experts["w_down"][home_f],
+                                preferred_element_type=jnp.float32
+                                ).astype(xt.dtype)       # (T*k, d)
     else:
         # Bucketed: pad capacity so the kernel grid tiles it, scatter the
         # SORTED tokens with one index build (dropped ranks scatter out of
         # range and vanish), leave unfilled rows pointing at a zero pad row.
-        cap_pad = align_capacity(cap, kc.block_c)
-        pe_sorted = pe_f[order]
-        pr_sorted = ps_f[order]
-        keep_sorted = keep_f[order]
-        dest = jnp.where(keep_sorted,
-                         pe_sorted * cap_pad + pr_sorted, n_phys * cap_pad)
-        src = jnp.full((n_phys * cap_pad,), t, jnp.int32).at[dest].set(
-            order // k, mode="drop")
-        x_pad = jnp.concatenate([xt, jnp.zeros((1, d), xt.dtype)], axis=0)
-        buf = x_pad[src].reshape(n_phys, cap_pad, d)
-        group_sizes = jnp.minimum(sizes, cap)            # logical frame
-        if spec is not None:
-            # Physical group g (replica j of expert e, r_e copies) holds the
-            # ranks ≡ j (mod r_e) below the logical group size: ceil((g-j)/r).
-            p2l = jnp.asarray(spec.phys_to_logical, jnp.int32)
-            j = jnp.arange(n_phys, dtype=jnp.int32) - base[p2l]
-            r_p = reps[p2l]
-            group_sizes = jnp.maximum(
-                0, (group_sizes[p2l] - j + r_p - 1) // r_p)
-        out_buf = kops.moe_ffn(
-            buf, experts["w_gate"], experts["w_up"], experts["w_down"],
-            act=act, interpret=kc.interpret,
-            group_sizes=group_sizes,
-            block_c=kc.block_c, block_f=kc.block_f)
-        flat_out = out_buf.reshape(n_phys * cap_pad, d)
-        safe = jnp.where(keep_f, pe_f * cap_pad + ps_f, 0)
-        picked = flat_out[safe]                          # (T*k, d)
+        with jax.named_scope("moe/dispatch"):
+            cap_pad = align_capacity(cap, kc.block_c)
+            pe_sorted = pe_f[order]
+            pr_sorted = ps_f[order]
+            keep_sorted = keep_f[order]
+            dest = jnp.where(keep_sorted,
+                             pe_sorted * cap_pad + pr_sorted,
+                             n_phys * cap_pad)
+            src = jnp.full((n_phys * cap_pad,), t, jnp.int32).at[dest].set(
+                order // k, mode="drop")
+            x_pad = jnp.concatenate([xt, jnp.zeros((1, d), xt.dtype)],
+                                    axis=0)
+            buf = x_pad[src].reshape(n_phys, cap_pad, d)
+            group_sizes = jnp.minimum(sizes, cap)        # logical frame
+            if spec is not None:
+                # Physical group g (replica j of expert e, r_e copies)
+                # holds the ranks ≡ j (mod r_e) below the logical group
+                # size: ceil((g-j)/r).
+                p2l = jnp.asarray(spec.phys_to_logical, jnp.int32)
+                j = jnp.arange(n_phys, dtype=jnp.int32) - base[p2l]
+                r_p = reps[p2l]
+                group_sizes = jnp.maximum(
+                    0, (group_sizes[p2l] - j + r_p - 1) // r_p)
+        with jax.named_scope("moe/experts"):
+            out_buf = kops.moe_ffn(
+                buf, experts["w_gate"], experts["w_up"], experts["w_down"],
+                act=act, interpret=kc.interpret,
+                group_sizes=group_sizes,
+                block_c=kc.block_c, block_f=kc.block_f)
+        with jax.named_scope("moe/combine"):
+            flat_out = out_buf.reshape(n_phys * cap_pad, d)
+            safe = jnp.where(keep_f, pe_f * cap_pad + ps_f, 0)
+            picked = flat_out[safe]                      # (T*k, d)
 
-    picked = jnp.where(keep_f[:, None], picked, 0.0)
-    y = jnp.zeros_like(xt).at[t_f].add(
-        picked * gates.reshape(-1)[:, None])
-    if "shared" in p:
-        y = y + ffn_apply(p["shared"], xt, act, pc)
-    if return_counts:
-        counts = routed_counts(idx, moe.n_experts)       # (T, E)
-        return (y.reshape(shape), aux,
-                counts.reshape(shape[:-1] + (moe.n_experts,)))
-    return y.reshape(shape), aux
+    with jax.named_scope("moe/combine"):
+        picked = jnp.where(keep_f[:, None], picked, 0.0)
+        y = jnp.zeros_like(xt).at[t_f].add(
+            picked * gates.reshape(-1)[:, None])
+    return _shared_and_counts(p, xt, y, idx, shape, aux, moe, act, pc,
+                              return_counts)
 
 
 # ---------------------------------------------------------------------------
@@ -537,7 +563,8 @@ def moe_apply_ep(p, x, moe, act: str, pc: ParallelContext,
     else:
         y, aux = out
     if "shared" in p:
-        y = y + ffn_apply(p["shared"], xt, act, pc)
+        with jax.named_scope("moe/experts"):
+            y = y + ffn_apply(p["shared"], xt, act, pc)
     if return_counts:
         return (y.reshape(shape), aux,
                 counts.reshape(shape[:-1] + (moe.n_experts,)))

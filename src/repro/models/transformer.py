@@ -13,10 +13,18 @@ Layer kinds:
   A zamba2 shared attention block (parameters shared across occurrences)
   C decoder layer with cross-attention (encoder-decoder)
   B bidirectional encoder layer
+
+Device work is named by ``jax.named_scope`` so a profiler trace can group
+it: ``layer_cache`` (a serving scan's own work: each block's cache sliced
+from the stacked cache and its new cache stacked back), ``layer_weights``
+(a serving block's weights sliced from the stack), ``attn`` with
+``attn/cache_write`` inside it, the MoE layer's ``moe/*`` (``models.moe``)
+and ``lm_head``. A trace names an op by the innermost of these.
 """
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from functools import partial
 
@@ -274,19 +282,21 @@ def _apply_layer(kind, p, x, entry, *, cfg, pc, mode, pos, pos3, length,
     if entry is not None:
         attn_cache = ({k: v for k, v in entry.items()
                        if k not in ("xk", "xv")} if kind == "C" else entry)
-    y, nc = block(pp["attn"], h, cfg=cfg, pos=pos, window=window,
-                  cache=attn_cache, length=length, mode=mode, pos3=pos3,
-                  flash_block=pc.flash_block)
+    with jax.named_scope("attn"):
+        y, nc = block(pp["attn"], h, cfg=cfg, pos=pos, window=window,
+                      cache=attn_cache, length=length, mode=mode, pos3=pos3,
+                      flash_block=pc.flash_block)
     x = x + y
 
     if kind == "C":
         hx = rmsnorm(p["ln_x"], x, cfg.norm_eps)
-        if mode == "decode":
-            kv = {"k": entry["xk"], "v": entry["xv"]}
-        else:  # train / prefill: fresh cross K/V from the encoder output
-            kv = attn_mod.encode_cross_kv(p["xattn"], enc_out)
-        yx = attn_mod.cross_attn_block(p["xattn"], hx, kv, cfg=cfg,
-                                       flash_block=pc.flash_block)
+        with jax.named_scope("attn"):
+            if mode == "decode":
+                kv = {"k": entry["xk"], "v": entry["xv"]}
+            else:  # train / prefill: fresh cross K/V from the encoder output
+                kv = attn_mod.encode_cross_kv(p["xattn"], enc_out)
+            yx = attn_mod.cross_attn_block(p["xattn"], hx, kv, cfg=cfg,
+                                           flash_block=pc.flash_block)
         x = x + yx
         if nc is not None:
             nc = dict(nc, xk=kv["k"], xv=kv["v"])
@@ -301,7 +311,9 @@ def _apply_layer(kind, p, x, entry, *, cfg, pc, mode, pos, pos3, length,
             y2, aux = moe_apply(p["moe"], h2, cfg.moe, cfg.act, pc)
     else:
         y2 = ffn_apply(pp["ffn"], h2, cfg.act, pc)
-    return x + y2, gate(nc), aux, counts
+    with jax.named_scope("attn/cache_write"):
+        nc = gate(nc)
+    return x + y2, nc, aux, counts
 
 
 def _run_segment(seg, seg_params, seg_cache, x, *, cfg, pc, mode, pos, pos3,
@@ -311,12 +323,27 @@ def _run_segment(seg, seg_params, seg_cache, x, *, cfg, pc, mode, pos, pos3,
 
     Returns (x, new_cache, stats, aux). ``stats`` is a tuple with one
     (count, B, S, E) array per MoE kind position when ``collect_stats``,
-    else an empty tuple."""
+    else an empty tuple.
+
+    Serving (a cache is present) scans block indices and slices each
+    block's weights from the stack itself, under the ``layer_weights``
+    scope, so the copies the device makes of them are named in a trace;
+    the scan runs under ``layer_cache``, which names the slices of the
+    stacked cache it feeds each block and the stacking of the new cache
+    it returns. Training scans the stack as ``xs``: a closed-over stack
+    would make the backward pass add a full-size cotangent in every
+    iteration."""
     with_cache = mode != "train"
+
+    def block_weights(i):
+        with jax.named_scope("layer_weights"):
+            return jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+                seg_params)
 
     def block(carry, xs):
         x, aux = carry
-        params = xs[0] if with_cache else xs
+        params = block_weights(xs[0]) if with_cache else xs
         cache = xs[1] if with_cache else (None,) * len(seg.kinds)
         new_entries = []
         stats = []
@@ -335,19 +362,22 @@ def _run_segment(seg, seg_params, seg_cache, x, *, cfg, pc, mode, pos, pos3,
 
     if remat:
         block = jax.checkpoint(block)
-    xs = (seg_params, seg_cache) if with_cache else seg_params
-    if pc.unroll_segments:
-        carry = (x, jnp.zeros((), jnp.float32))
-        ys = []
-        for b in range(seg.count):
-            xs_b = jax.tree.map(lambda t: t[b], xs)
-            carry, y = block(carry, xs_b)
-            ys.append(y)
-        (x, aux) = carry
-        new_cache, stats = jax.tree.map(lambda *ls: jnp.stack(ls), *ys)
-        return x, new_cache if with_cache else None, stats, aux
-    (x, aux), (new_cache, stats) = jax.lax.scan(
-        block, (x, jnp.zeros((), jnp.float32)), xs, length=seg.count)
+    xs = ((jnp.arange(seg.count), seg_cache) if with_cache
+          else seg_params)
+    with (jax.named_scope("layer_cache") if with_cache
+          else contextlib.nullcontext()):
+        if pc.unroll_segments:
+            carry = (x, jnp.zeros((), jnp.float32))
+            ys = []
+            for b in range(seg.count):
+                xs_b = jax.tree.map(lambda t: t[b], xs)
+                carry, y = block(carry, xs_b)
+                ys.append(y)
+            (x, aux) = carry
+            new_cache, stats = jax.tree.map(lambda *ls: jnp.stack(ls), *ys)
+            return x, new_cache if with_cache else None, stats, aux
+        (x, aux), (new_cache, stats) = jax.lax.scan(
+            block, (x, jnp.zeros((), jnp.float32)), xs, length=seg.count)
     return x, new_cache, stats, aux
 
 
@@ -437,8 +467,10 @@ def forward(params, cfg, *, tokens=None, embeds=None, mode="train",
         stats_parts.extend(stats)
 
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    head = (params["embed"].T if cfg.tie_embeddings else params["lm_head"])
-    logits = x @ head.astype(x.dtype)
+    with jax.named_scope("lm_head"):
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        logits = x @ head.astype(x.dtype)
     new_cache = None
     if mode != "train" and cache is not None:
         inc = jnp.asarray(s if mode == "prefill" else 1, jnp.int32)

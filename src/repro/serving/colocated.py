@@ -295,10 +295,7 @@ class ColocatedContinuousEngine:
             [self.model_a, self.model_b],
             collect_stats=self.replan is not None, jit=self._jit))
         if self._telemetry is not None:
-            step = self._telemetry.wrap_step(
-                step, "lockstep_decode",
-                rounds=lambda: getattr(self.model_a.pc, "aurora_rounds",
-                                       None))
+            step = self._telemetry.wrap_step(step, "lockstep_decode")
         self._step = step
 
     @property
@@ -548,10 +545,7 @@ class MultiTenantContinuousEngine:
             self.models, collect_stats=self.replan is not None,
             jit=self._jit))
         if self._telemetry is not None:
-            step = self._telemetry.wrap_step(
-                step, "lockstep_decode",
-                rounds=lambda: getattr(self.models[0].pc, "aurora_rounds",
-                                       None))
+            step = self._telemetry.wrap_step(step, "lockstep_decode")
         self._step = step
 
     @property

@@ -560,11 +560,11 @@ class EngineConfig:
     compose their mesh-context wrapper under it); ``jit=False`` runs steps
     eagerly (debugging).
 
-    ``telemetry`` attaches a ``repro.serving.Telemetry`` hub: compiled
-    steps become spans, shed/replan/fault/adoption events publish to the
-    hub's bus, and the metrics registry fills in. ``None`` (default) is
-    the zero-overhead path — no wrapper is composed and no per-step work
-    happens. The hub is shared by colocated/multi-tenant pools (pool
+    ``telemetry`` attaches a ``repro.serving.Telemetry`` hub: the
+    engines' scheduling, dispatch, sampling and read-back become spans,
+    shed/replan/fault/adoption events publish to the hub's bus, and the
+    metrics registry fills in. ``None`` (default) costs one attribute
+    test per span site and no per-step work. The hub is shared by colocated/multi-tenant pools (pool
     configs are ``dataclasses.replace`` copies). ``event_capacity``
     bounds the per-engine event rings (``shed_events``), drop-oldest.
     """

@@ -63,6 +63,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import itertools
 import math
 from functools import partial
 from typing import Sequence
@@ -76,7 +77,7 @@ from repro.models import Model
 from repro.serving.config import (EngineConfig, RequestSpec, ShedEvent,
                                   coerce_config, make_bucketer)
 from repro.serving.events import RingBuffer
-from repro.serving.telemetry import STEP_BOUNDS, record_adoption
+from repro.serving.telemetry import _NULL_SPAN, STEP_BOUNDS, record_adoption
 
 __all__ = ["Request", "poisson_requests", "serve_stream", "make_bucketer",
            "ServingEngine", "ContinuousEngine"]
@@ -93,6 +94,9 @@ class Request:
     deadline: float | None = None
     tenant: object = None                # opaque tenant id for the policy
     out_tokens: list = dataclasses.field(default_factory=list)
+    # Process-unique id: every telemetry span of this request carries it.
+    rid: int = dataclasses.field(default_factory=itertools.count().__next__,
+                                 compare=False)
 
 
 def poisson_requests(rng, n: int, rate: float, vocab: int, prompt_len: int,
@@ -136,6 +140,18 @@ def serve_stream(step_fn, pools) -> None:
             t = max(t + 1.0, min(due))               # jump idle gaps
         else:
             t += 1.0
+
+
+def _chunk_attrs(r: Request, slot: int, total: int, done: int,
+                 c: int) -> dict:
+    """``prefill_chunk`` span attributes for padded positions [done,
+    done + c) of a ``total``-token left-padded prompt: the real prompt
+    tokens it holds, the first one's position in the prompt, and whether
+    the chunk ends the prompt."""
+    pad = total - len(r.prompt)
+    lo, hi = max(done, pad), max(done + c, pad)
+    return {"rid": r.rid, "slot": slot, "real": hi - lo, "start": lo - pad,
+            "last": done + c >= total}
 
 
 class ServingEngine:
@@ -249,11 +265,10 @@ class ContinuousEngine:
         # mesh context (bare ``PartitionSpec`` sharding constraints resolve
         # against it); identity for the single-device engines.
         self._step_wrapper = config.step_wrapper or (lambda fn: fn)
-        # Optional telemetry hub (``config.telemetry``): compiled steps get
-        # span-wrapped in ``_build_steps`` and the scheduler publishes
-        # shed/adoption events + queue/TTFT/token metrics. None (default)
-        # keeps the exact untelemetered code path — no wrapper, no per-step
-        # work.
+        # Optional telemetry hub (``config.telemetry``): spans at the
+        # scheduler's boundaries (``step``'s span tree) plus shed/adoption
+        # events and queue/TTFT/token metrics. None (default) costs one
+        # attribute test per span site.
         self._telemetry = config.telemetry
         self._tenant_label = (self.tenant_spec.name
                               if self.tenant_spec is not None else "")
@@ -266,54 +281,59 @@ class ContinuousEngine:
         # on ``shed_events.dropped``.
         self.shed_events: RingBuffer = RingBuffer(config.event_capacity)
 
-    def _live_rounds(self):
-        """The CURRENT BvN round schedule (None off the distributed path).
-        Read through ``self.model`` at call time so telemetry follows
-        mid-stream rounds swaps (``_rebind``)."""
-        return getattr(self.model.pc, "aurora_rounds", None)
+    @property
+    def telemetry(self):
+        """The attached ``Telemetry`` hub, or None. Spans are taken at the
+        call sites from this attribute, so a hub set on a live engine
+        records from the next step on, and setting None stops it."""
+        return self._telemetry
 
-    def _wrap_step_fn(self, fn, name: str, rounds: bool = False):
-        """Compose the step wrappers for one compiled step: the configured
-        ``step_wrapper`` (mesh context / fault injection) innermost, the
-        telemetry span wrapper — when a hub is attached — outermost, so
-        span timing covers the full wrapped call."""
-        fn = self._step_wrapper(fn)
-        tel = self._telemetry
-        if tel is None:
-            return fn
-        return tel.wrap_step(fn, name, tenant=self._tenant_label or None,
-                             rounds=self._live_rounds if rounds else None)
+    @telemetry.setter
+    def telemetry(self, hub) -> None:
+        self._telemetry = hub
 
     def _build_steps(self) -> None:
-        """(Re)build the jitted step programs from ``self.model``."""
-        model, jit, wrap = self.model, self._jit, self._wrap_step_fn
+        """(Re)build the jitted step programs from ``self.model``, each
+        under the configured ``step_wrapper`` (mesh context / fault
+        injection). The programs are named functions, so a device trace
+        shows ``jit_prefill_slot``, ``jit_prefill_chunk_first``,
+        ``jit_prefill_chunk`` and ``jit_decode_step``."""
+        model, jit, wrap = self.model, self._jit, self._step_wrapper
         stats = self.monitor is not None
-        fn_p = partial(model.prefill_slot, cap=self.cache_cap,
-                       src_len=self.src_len, collect_moe_stats=stats)
-        self._prefill = wrap(jax.jit(fn_p, donate_argnums=(2,))
-                             if jit else fn_p, "prefill")
+        cap, src_len = self.cache_cap, self.src_len
+
+        def prefill_slot(params, inputs, cache, slot):
+            return model.prefill_slot(params, inputs, cache, slot, cap=cap,
+                                      src_len=src_len,
+                                      collect_moe_stats=stats)
+
         # Chunked prefill runs straight against the shared per-slot cache:
         # each chunk slices the slot row, continues the prefill, and merges
         # back in ONE donated program (``Model.prefill_chunk_slot``) — no
         # detached batch-1 cache lives on the host between chunks.
-        fn_c0 = partial(model.prefill_chunk_slot, first=True,
-                        cap=self.cache_cap, src_len=self.src_len,
-                        collect_moe_stats=stats)
-        self._chunk_first = wrap(jax.jit(fn_c0, donate_argnums=(2,))
-                                 if jit else fn_c0, "prefill_chunk")
-        fn_c = partial(model.prefill_chunk_slot, first=False,
-                       cap=self.cache_cap, src_len=self.src_len,
-                       collect_moe_stats=stats)
-        self._chunk = wrap(jax.jit(fn_c, donate_argnums=(2,))
-                           if jit else fn_c, "prefill_chunk")
-        fn_d = model.decode_step_stats if stats else model.decode_step
-        self._decode = wrap(jax.jit(fn_d, donate_argnums=(2,))
-                            if jit else fn_d, "decode_step", rounds=True)
+        def prefill_chunk_first(params, inputs, cache, slot):
+            return model.prefill_chunk_slot(
+                params, inputs, cache, slot, first=True, cap=cap,
+                src_len=src_len, collect_moe_stats=stats)
+
+        def prefill_chunk(params, inputs, cache, slot):
+            return model.prefill_chunk_slot(
+                params, inputs, cache, slot, first=False, cap=cap,
+                src_len=src_len, collect_moe_stats=stats)
+
+        def compiled(fn, **kw):
+            return wrap(jax.jit(fn, **kw) if jit else fn)
+
+        self._prefill = compiled(prefill_slot, donate_argnums=(2,))
+        self._chunk_first = compiled(prefill_chunk_first, donate_argnums=(2,))
+        self._chunk = compiled(prefill_chunk, donate_argnums=(2,))
+        self._decode = compiled(
+            model.decode_step_stats if stats else model.decode_step,
+            donate_argnums=(2,))
         if self._pool_size > 1:
-            fn_pool = self._make_pool_fn(stats)
-            self._pool_step = wrap(
-                jax.jit(fn_pool, static_argnums=(0, 1), donate_argnums=(4,))
-                if jit else fn_pool, "pool_step", rounds=True)
+            self._pool_step = compiled(self._make_pool_fn(stats),
+                                       static_argnums=(0, 1),
+                                       donate_argnums=(4,))
 
     def _make_pool_fn(self, stats: bool):
         """The pooled-admission program: K chunked prefills (and, when
@@ -595,13 +615,15 @@ class ContinuousEngine:
     def _finish_admission(self, r: Request, slot: int, logits) -> None:
         """Shared tail of one-shot and chunked admission: emit the first
         token and occupy the slot (unless the request is already done)."""
-        tok0 = int(jnp.argmax(logits[0, -1, : self.model.cfg.vocab]))
-        if r.max_new_tokens > 0:
-            r.out_tokens.append(tok0)
-        if len(r.out_tokens) < r.max_new_tokens:
-            self.slots[slot] = r
-            self.tokens = self.tokens.at[slot, 0].set(tok0)
         tel = self._telemetry
+        with (_NULL_SPAN if tel is None
+              else tel.span("first_token", rid=r.rid, slot=slot)):
+            tok0 = int(jnp.argmax(logits[0, -1, : self.model.cfg.vocab]))
+            if r.max_new_tokens > 0:
+                r.out_tokens.append(tok0)
+            if len(r.out_tokens) < r.max_new_tokens:
+                self.slots[slot] = r
+                self.tokens = self.tokens.at[slot, 0].set(tok0)
         if tel is not None and tel.enabled and r.max_new_tokens > 0:
             tel.count("serving_tokens_total",
                       help="tokens emitted", tenant=self._tenant_label)
@@ -614,15 +636,19 @@ class ContinuousEngine:
     def _admit(self) -> None:
         """Drain the queue into free slots (one-shot per-slot prefill each,
         in the policy's queue order)."""
+        tel = self._telemetry
         while self.queue and None in self.slots:
-            slot = self.slots.index(None)
-            r = self._pop_queue()
-            p = self._bucket(len(r.prompt))
-            toks = np.zeros((1, p), np.int32)
-            toks[0, p - len(r.prompt):] = r.prompt      # left-pad with 0
-            out = self._prefill(
-                self.params, {"tokens": jnp.asarray(toks)}, self.cache,
-                jnp.int32(slot))
+            with _NULL_SPAN if tel is None else tel.span("admit"):
+                slot = self.slots.index(None)
+                r = self._pop_queue()
+                p = self._bucket(len(r.prompt))
+                toks = np.zeros((1, p), np.int32)
+                toks[0, p - len(r.prompt):] = r.prompt  # left-pad with 0
+                inputs = {"tokens": jnp.asarray(toks)}
+            with (_NULL_SPAN if tel is None else tel.span(
+                    "prefill", rid=r.rid, slot=slot, real=len(r.prompt))):
+                out = self._prefill(self.params, inputs, self.cache,
+                                    jnp.int32(slot))
             if self.monitor is not None:
                 logits, self.cache, stats = out
                 self._observe_prefill(stats, pad=p - len(r.prompt))
@@ -656,26 +682,32 @@ class ContinuousEngine:
         chunk lands directly in the slot's row of the shared cache; between
         chunks the decode step freezes that row (``row_mask``), so the
         partial state survives interleaved decode ticks untouched."""
-        if not self._pending:
-            slot = self._free_slot()
-            if not self.queue or slot is None:
+        tel = self._telemetry
+        with _NULL_SPAN if tel is None else tel.span("admit"):
+            if not self._pending:
+                slot = self._free_slot()
+                if not self.queue or slot is None:
+                    return False
+                self._start_pending(slot)
+            r, slot, toks, done = self._pending[0]
+            c = min(self.prefill_chunk, toks.shape[1] - done)
+            # Decode always runs and eats num_active tokens of any budget;
+            # the chunk only proceeds when the policy admits it. Progress
+            # is guaranteed: decode drains slots, so num_active falls and
+            # the leftover eventually covers a chunk (or the pool empties
+            # and the budget gate is bypassed entirely).
+            if not self.admission.select(self.num_active,
+                                         [self._spec(r, c)]):
                 return False
-            self._start_pending(slot)
-        r, slot, toks, done = self._pending[0]
-        c = min(self.prefill_chunk, toks.shape[1] - done)
-        # Decode always runs and eats num_active tokens of any budget; the
-        # chunk only proceeds when the policy admits it. Progress is
-        # guaranteed: decode drains slots, so num_active falls and the
-        # leftover eventually covers a chunk (or the pool empties and the
-        # budget gate is bypassed entirely).
-        if not self.admission.select(self.num_active, [self._spec(r, c)]):
-            return False
-        chunk_toks = {"tokens": jnp.asarray(toks[:, done:done + c])}
+            chunk_toks = {"tokens": jnp.asarray(toks[:, done:done + c])}
         # The first chunk starts the slot from a fresh zero state (no
         # leakage from the previous occupant); later chunks resume from the
         # slot's own recorded fill level.
         fn = self._chunk_first if done == 0 else self._chunk
-        out = fn(self.params, chunk_toks, self.cache, jnp.int32(slot))
+        with (_NULL_SPAN if tel is None else tel.span(
+                "prefill_chunk", **_chunk_attrs(r, slot, toks.shape[1],
+                                                done, c))):
+            out = fn(self.params, chunk_toks, self.cache, jnp.int32(slot))
         if self.monitor is not None:
             logits, self.cache, stats = out
             # The chunk covers padded positions [done, done+c); left-pad
@@ -707,30 +739,35 @@ class ContinuousEngine:
         wholesale with this step's argmax, so it must land BEFORE
         ``_finish_admission`` writes a freshly admitted slot's first token.
         """
-        while len(self._pending) < self._pool_size and self.queue:
-            slot = self._free_slot()
-            if slot is None:
-                break
-            self._start_pending(slot)
-        chunks = [min(self.prefill_chunk, p[2].shape[1] - p[3])
-                  for p in self._pending]
-        specs = [self._spec(p[0], c)
-                 for p, c in zip(self._pending, chunks)]
-        picked = self._check_selection(
-            self.admission.select(self.num_active, specs), len(specs))
-        decode = fuse_decode and self.num_active > 0
-        if not picked and not decode:
-            return False
-        sel = [self._pending[i] for i in picked]
-        sel_chunks = [chunks[i] for i in picked]
-        toks = tuple({"tokens": jnp.asarray(p[2][:, p[3]:p[3] + c])}
-                     for p, c in zip(sel, sel_chunks))
-        slot_ids = tuple(jnp.int32(p[1]) for p in sel)
-        firsts = tuple(p[3] == 0 for p in sel)
-        mask = np.array([r is not None for r in self.slots], bool)
-        chunk_out, dec_out, self.cache = self._pool_step(
-            firsts, bool(decode), self.params, toks, self.cache, slot_ids,
-            self.tokens, jnp.asarray(mask))
+        tel = self._telemetry
+        with _NULL_SPAN if tel is None else tel.span("admit"):
+            while len(self._pending) < self._pool_size and self.queue:
+                slot = self._free_slot()
+                if slot is None:
+                    break
+                self._start_pending(slot)
+            chunks = [min(self.prefill_chunk, p[2].shape[1] - p[3])
+                      for p in self._pending]
+            specs = [self._spec(p[0], c)
+                     for p, c in zip(self._pending, chunks)]
+            picked = self._check_selection(
+                self.admission.select(self.num_active, specs), len(specs))
+            decode = fuse_decode and self.num_active > 0
+            if not picked and not decode:
+                return False
+            sel = [self._pending[i] for i in picked]
+            sel_chunks = [chunks[i] for i in picked]
+            toks = tuple({"tokens": jnp.asarray(p[2][:, p[3]:p[3] + c])}
+                         for p, c in zip(sel, sel_chunks))
+            slot_ids = tuple(jnp.int32(p[1]) for p in sel)
+            firsts = tuple(p[3] == 0 for p in sel)
+            mask = np.array([r is not None for r in self.slots], bool)
+        with (_NULL_SPAN if tel is None else tel.span(
+                "pool_step", chunks=len(sel),
+                **(self._decode_attrs() if decode else {}))):
+            chunk_out, dec_out, self.cache = self._pool_step(
+                firsts, bool(decode), self.params, toks, self.cache,
+                slot_ids, self.tokens, jnp.asarray(mask))
         if decode:
             dlogits, dstats = dec_out
             if self.monitor is not None:
@@ -791,19 +828,23 @@ class ContinuousEngine:
 
     def _postdecode(self, logits) -> None:
         """Emit one token per occupied slot; evict finished requests."""
-        nxt = jnp.argmax(logits[:, :, : self.model.cfg.vocab],
-                         axis=-1).astype(jnp.int32)
-        self.tokens = nxt
-        host = np.asarray(nxt)
-        emitted = 0
-        for i, r in enumerate(self.slots):
-            if r is None:
-                continue
-            r.out_tokens.append(int(host[i, 0]))
-            emitted += 1
-            if len(r.out_tokens) >= r.max_new_tokens:
-                self.slots[i] = None                     # slot free for reuse
         tel = self._telemetry
+        with _NULL_SPAN if tel is None else tel.span("sample"):
+            nxt = jnp.argmax(logits[:, :, : self.model.cfg.vocab],
+                             axis=-1).astype(jnp.int32)
+            self.tokens = nxt
+        with _NULL_SPAN if tel is None else tel.span("readback"):
+            host = np.asarray(nxt)
+        emitted = 0
+        with (_NULL_SPAN if tel is None
+              else tel.span("emit", emitted=self.num_active)):
+            for i, r in enumerate(self.slots):
+                if r is None:
+                    continue
+                r.out_tokens.append(int(host[i, 0]))
+                emitted += 1
+                if len(r.out_tokens) >= r.max_new_tokens:
+                    self.slots[i] = None                 # slot free for reuse
         if tel is not None and tel.enabled and emitted:
             tel.count("serving_tokens_total", emitted,
                       help="tokens emitted", tenant=self._tenant_label)
@@ -817,15 +858,26 @@ class ContinuousEngine:
         — attention is batch-row independent — so masking never changes
         emitted tokens."""
         mask = np.array([r is not None for r in self.slots], bool)
+        tel = self._telemetry
+        with (_NULL_SPAN if tel is None
+              else tel.span("decode_step", **self._decode_attrs())):
+            out = self._decode(self.params, self.tokens, self.cache,
+                               jnp.asarray(mask))
         if self.monitor is not None:
-            logits, self.cache, stats = self._decode(self.params, self.tokens,
-                                                     self.cache,
-                                                     jnp.asarray(mask))
+            logits, self.cache, stats = out
             self._observe_decode_routing(stats, mask)
         else:
-            logits, self.cache = self._decode(self.params, self.tokens,
-                                              self.cache, jnp.asarray(mask))
+            logits, self.cache = out
         return logits
+
+    def _decode_attrs(self) -> dict:
+        """``decode_step`` span attributes: the slots decoded and their
+        cache lengths after this step (padded prompt plus the tokens fed
+        back), summed."""
+        live = [r for r in self.slots if r is not None]
+        return {"active": len(live),
+                "valid": sum(self._bucket(len(r.prompt)) + len(r.out_tokens)
+                             for r in live)}
 
     def step(self) -> bool:
         """Admit (whole prefills, or policy-admitted chunks), then decode

@@ -2,34 +2,34 @@
 
 One :class:`Telemetry` hub threads through every engine family via
 ``EngineConfig(telemetry=...)`` and answers the questions the paper's
-§6 measurements ask of a live system — which ppermute round is the step
-spending its time in, which expert is hot, which tenant is burning its
-TTFT budget — without touching the compiled programs (telemetry never
-changes tokens; it only watches).
+§6 measurements ask of a live system — where a step's host time goes,
+which expert is hot, which tenant is burning its TTFT budget — without
+touching the compiled programs (telemetry never changes tokens; it only
+watches).
 
 Three surfaces:
 
 * **Metrics registry** — labelled counters / gauges / histograms
   (tokens, TTFT/TPOT per tenant, expert-load imbalance and estimated
-  drop rate per layer, ppermute round counts/bytes, replan / shed /
-  fault / adoption totals, queue depth, per-device step-time EWMAs)
+  drop rate per layer, replan / shed / fault / adoption totals, queue
+  depth, per-device step-time EWMAs)
   with Prometheus text exposition and a JSON snapshot.
 * **Structured spans** — nested, exception-safe ``span("decode_step")``
-  records captured around the jitted steps through the existing
-  ``step_wrapper`` seam, exported as JSONL and as Chrome trace-event
-  JSON (open the file directly in Perfetto / ``chrome://tracing``).
-  For engines with a BvN round schedule the compiled-step window is
-  subdivided into per-round ``dispatch_round`` child spans (host-side
-  reconstruction of the paper's Fig. 3 view: timing is the measured
-  step split evenly across rounds, marked ``estimated``).
+  records at the engines' layer boundaries (scheduling, program
+  dispatch, sampling, token read-back), exported as JSONL and as Chrome
+  trace-event JSON (open the file directly in Perfetto /
+  ``chrome://tracing``). A span around a dispatch ends when the dispatch
+  returns: device time is the profiler's to measure. With
+  ``jax_profiler=True`` every span also enters a
+  ``jax.profiler.TraceAnnotation`` carrying its attributes, so the same
+  spans land in a device trace on the device's clock.
 * **Event bus** — ``ShedEvent`` / ``ReplanEvent`` / ``FaultEvent`` /
   adoption / recovery notices publish into one bounded, deterministic
   stream (:mod:`repro.serving.events`) that interleaves with spans in
   the exports.
 
-Disabled is free: ``EngineConfig(telemetry=None)`` (the default) keeps
-every engine on the exact pre-telemetry code path — no wrapper, no
-per-step allocation — and ``Telemetry(enabled=False)`` is a cheap
+Disabled is free: ``EngineConfig(telemetry=None)`` (the default) costs
+one attribute test per span site — no span object, no annotation — and ``Telemetry(enabled=False)`` is a cheap
 runtime off-switch (``span`` returns a shared no-op context manager).
 """
 
@@ -300,7 +300,8 @@ class _Span:
     """Live span context manager; exception-safe (closes in ``__exit__``
     regardless, recording the exception type and re-raising)."""
 
-    __slots__ = ("_hub", "name", "attrs", "ts", "dur", "depth", "record")
+    __slots__ = ("_hub", "name", "attrs", "ts", "dur", "depth", "record",
+                 "_ann")
 
     def __init__(self, hub: "Telemetry", name: str, attrs: dict):
         self._hub = hub
@@ -310,17 +311,26 @@ class _Span:
         self.dur = 0.0
         self.depth = 0
         self.record: SpanRecord | None = None
+        self._ann = None
 
     def __enter__(self):
         hub = self._hub
         self.depth = len(hub._stack)
         hub._stack.append(self)
+        if hub._annotation is not None:
+            self._ann = hub._annotation(
+                self.name, **{k: v for k, v in self.attrs.items()
+                              if v is not None})
+            self._ann.__enter__()
         self.ts = hub._clock()
         return self
 
     def __exit__(self, exc_type, exc, tb):
         hub = self._hub
         self.dur = hub._clock() - self.ts
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         # Pop self even if an inner span leaked (exception paths): the
         # stack is truncated back to this span's depth.
         del hub._stack[self.depth:]
@@ -349,21 +359,21 @@ class Telemetry:
         (``span`` / ``count`` / ``gauge`` / ``observe`` / ``publish`` /
         wrapped steps) is a guarded no-op with no per-call allocation.
     jax_profiler:
-        When True, wrapped compiled steps also enter a
-        ``jax.profiler.TraceAnnotation`` so host spans line up with
-        device traces captured by ``jax.profiler``.
-    block_steps:
-        When True (default) wrapped compiled steps call
-        ``jax.block_until_ready`` on their outputs so span durations
-        measure execution, not dispatch.  Only affects enabled hubs.
+        When True, every span also enters a
+        ``jax.profiler.TraceAnnotation(name, **attrs)``, so a trace
+        captured by ``jax.profiler`` holds the same spans (attributes as
+        event stats; ``None`` attributes left out) on the device's clock.
     """
 
     def __init__(self, capacity: int = 4096, enabled: bool = True,
-                 jax_profiler: bool = False, block_steps: bool = True,
+                 jax_profiler: bool = False,
                  clock: Callable[[], float] = time.perf_counter):
         self.enabled = bool(enabled)
         self.jax_profiler = bool(jax_profiler)
-        self.block_steps = bool(block_steps)
+        self._annotation = None
+        if self.jax_profiler:
+            import jax.profiler
+            self._annotation = jax.profiler.TraceAnnotation
         self._clock = clock
         self.metrics = MetricsRegistry()
         self._spans_dropped = self.metrics.counter(
@@ -399,15 +409,6 @@ class Telemetry:
         self.spans.append(rec)
         self._span_seconds.observe(rec.dur, name=rec.name)
 
-    def emit_span(self, name: str, ts: float, dur: float, depth: int = 0,
-                  **attrs) -> SpanRecord:
-        """Record a synthetic (already-timed) span, e.g. per-round
-        subdivisions of a measured compiled-step window."""
-        rec = SpanRecord(name=name, ts=ts, dur=dur, depth=depth,
-                         seq=self._next_span_seq(), attrs=attrs)
-        self._finish_span(rec)
-        return rec
-
     # -- metrics shorthands (no-ops when disabled) -------------------------
 
     def count(self, name: str, amount: float = 1.0, help: str = "",
@@ -439,67 +440,20 @@ class Telemetry:
 
     # -- step wrapping (the step_wrapper seam) -----------------------------
 
-    def wrap_step(self, fn: Callable, name: str, tenant: str | None = None,
-                  rounds: Callable[[], Any] | None = None) -> Callable:
-        """Wrap a compiled step so each call is a span.
-
-        ``rounds`` (optional) returns the engine's *current* BvN round
-        schedule; when present and non-empty, the measured step window
-        is subdivided into per-round ``dispatch_round`` child spans
-        (equal split, ``estimated=True`` — a host can't see intra-step
-        device timing without a device profiler).
-        """
+    def wrap_step(self, fn: Callable, name: str,
+                  tenant: str | None = None) -> Callable:
+        """Wrap a compiled step so each call is a span around its dispatch
+        (the call returns before the device finishes; device time is the
+        profiler's)."""
         attrs = {} if tenant is None else {"tenant": tenant}
 
         def wrapped(*args, **kwargs):
             if not self.enabled:
                 return fn(*args, **kwargs)
-            sp = _Span(self, name, dict(attrs))
-            with sp:
-                ann = None
-                if self.jax_profiler:
-                    try:
-                        import jax.profiler
-                        ann = jax.profiler.TraceAnnotation(name)
-                        ann.__enter__()
-                    except Exception:
-                        ann = None
-                try:
-                    out = fn(*args, **kwargs)
-                    if self.block_steps:
-                        import jax
-                        out = jax.block_until_ready(out)
-                finally:
-                    if ann is not None:
-                        ann.__exit__(None, None, None)
-            if rounds is not None:
-                self._emit_rounds(sp, rounds(), tenant)
-            return out
+            with _Span(self, name, dict(attrs)):
+                return fn(*args, **kwargs)
 
         return wrapped
-
-    def _emit_rounds(self, sp: _Span, rounds, tenant: str | None) -> None:
-        if rounds is None:
-            return
-        r_list = list(rounds)
-        n = len(r_list)
-        if n == 0:
-            return
-        sub = sp.dur / n
-        for i, perm in enumerate(r_list):
-            attrs = {"r": i, "estimated": True, "parent": sp.name,
-                     "perm": _jsonable(perm)}
-            if tenant is not None:
-                attrs["tenant"] = tenant
-            self.emit_span("dispatch_round", ts=sp.ts + i * sub, dur=sub,
-                           depth=sp.depth + 1, **attrs)
-        self.metrics.counter(
-            "ppermute_rounds_total",
-            "BvN dispatch rounds executed (per compiled step x schedule "
-            "length)").inc(n)
-        self.metrics.gauge(
-            "ppermute_rounds_per_step",
-            "length of the live BvN round schedule").set(n)
 
     # -- exports -----------------------------------------------------------
 

@@ -204,9 +204,9 @@ def test_jsonl_and_chrome_trace_round_trip():
     with tel.span("engine_step", step=0):
         with tel.span("decode_step", tenant="a"):
             pass
+        with tel.span("readback"):
+            pass
     tel.publish("shed", {"reason": "deadline:late"}, step=0)
-    tel.emit_span("dispatch_round", ts=0.0, dur=0.001, depth=2, r=0,
-                  estimated=True)
     for line in tel.jsonl().splitlines():
         json.loads(line)               # every JSONL line round-trips
     trace = json.loads(json.dumps(tel.chrome_trace()))
@@ -215,7 +215,7 @@ def test_jsonl_and_chrome_trace_round_trip():
     ts = [e["ts"] for e in trace["traceEvents"] if e["ph"] != "M"]
     assert ts == sorted(ts)            # timeline order
     names = {e["name"] for e in trace["traceEvents"]}
-    assert {"engine_step", "decode_step", "dispatch_round", "shed"} <= names
+    assert {"engine_step", "decode_step", "readback", "shed"} <= names
     # tenant maps to its own track with a thread_name record
     tids = {e["tid"] for e in trace["traceEvents"]
             if e["ph"] == "X" and e["args"].get("tenant") == "a"}
@@ -369,3 +369,137 @@ def test_health_gauges_exported():
 def test_event_capacity_validated():
     with pytest.raises(ValueError):
         EngineConfig(event_capacity=0)
+
+
+# -- engine spans ------------------------------------------------------------
+
+def _chunked_requests():
+    return [Request(prompt=list(range(1, 20)), max_new_tokens=5),
+            Request(prompt=[3, 1, 4, 1, 5], max_new_tokens=4),
+            Request(prompt=list(range(30, 41)), max_new_tokens=3)]
+
+
+def _serve_chunked(telemetry):
+    cfg, model, params = _model("phi3.5-moe-42b-a6.6b")
+    eng = ContinuousEngine(model, params, 2, 48,
+                           config=EngineConfig(prefill_chunk=8, kernels=True,
+                                               bucket_policy="step:8",
+                                               telemetry=telemetry))
+    reqs = _chunked_requests()
+    eng.serve(reqs)
+    return [r.out_tokens for r in reqs]
+
+
+def test_spans_change_no_token_and_cost_nothing_when_absent(monkeypatch):
+    """Chunked prefill on the kernel path: byte-identical tokens with the
+    hub attached (annotating the profiler), disabled, or absent; with no
+    hub no ``TraceAnnotation`` is entered, with one each span enters one."""
+    entered = []
+
+    class Counting:
+        def __init__(self, name, **attrs):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+    base = _serve_chunked(None)
+    assert entered == []
+    assert _serve_chunked(Telemetry(enabled=False)) == base
+    assert entered == []
+    tel = Telemetry(jax_profiler=True)
+    assert _serve_chunked(tel) == base
+    assert entered == [s.name for s in sorted(tel.spans, key=lambda s: s.ts)]
+    assert {"engine_step", "admit", "prefill_chunk", "first_token",
+            "decode_step", "sample", "readback", "emit"} <= set(entered)
+
+
+def test_span_tree_and_request_ids():
+    """Every child of an ``engine_step`` sits one level below it; every
+    span of one request's chunks and first token carries its ``rid``."""
+    tel = Telemetry()
+    cfg, model, params = _model("phi3.5-moe-42b-a6.6b")
+    eng = ContinuousEngine(model, params, 2, 48,
+                           config=EngineConfig(prefill_chunk=8,
+                                               bucket_policy="step:8",
+                                               telemetry=tel))
+    reqs = _chunked_requests()
+    eng.serve(reqs)
+    recs = sorted(tel.spans, key=lambda s: s.ts)
+    assert {s.depth for s in recs if s.name == "engine_step"} == {0}
+    assert {s.depth for s in recs if s.name != "engine_step"} == {1}
+    rids = {r.rid for r in reqs}
+    assert len(rids) == len(reqs)
+    chunks = [s for s in recs if s.name == "prefill_chunk"]
+    # 19 -> 24 (3 chunks), 5 -> 8 (1), 11 -> 16 (2)
+    assert [c.attrs["rid"] for c in chunks].count(reqs[0].rid) == 3
+    assert {c.attrs["rid"] for c in chunks} == rids
+    assert sum(c.attrs["real"] for c in chunks) == 19 + 5 + 11
+    assert [c.attrs["last"] for c in chunks].count(True) == 3
+    firsts = [s.attrs["rid"] for s in recs if s.name == "first_token"]
+    assert sorted(firsts) == sorted(rids)
+    emitted = sum(s.attrs["emitted"] for s in recs if s.name == "emit")
+    assert emitted + len(firsts) == sum(len(r.out_tokens) for r in reqs)
+
+
+def _scopes_in(program) -> set:
+    """Scope names in a compiled program's HLO metadata, or in a lowered
+    program's locations (where the compiler has not yet folded away the
+    ops of a one-device collective)."""
+    import re
+    if isinstance(program, jax.stages.Compiled):
+        paths = re.findall(r'op_name="([^"]*)"', program.as_text())
+    else:
+        paths = re.findall(r'loc\("([^"]*)"',
+                           program.as_text(debug_info=True))
+    names = set()
+    for path in paths:
+        parts = path.split("/")
+        names.update(parts)
+        names.update("/".join(parts[i:i + 2]) for i in range(len(parts)))
+    return names
+
+
+def test_lowered_programs_carry_model_scopes():
+    """The compiled decode and chunk programs name their device work: the
+    layer scan's weight slices, attention and its cache write, the MoE
+    layer's parts and the output head."""
+    cfg, model, params = _model("phi3.5-moe-42b-a6.6b")
+    eng = ContinuousEngine(model, params, 2, 32,
+                           config=EngineConfig(prefill_chunk=8, kernels=True,
+                                               bucket_policy="step:8"))
+    want = {"layer_weights", "attn", "attn/cache_write", "moe/router",
+            "moe/dispatch", "moe/experts", "moe/combine", "lm_head"}
+    mask = jax.numpy.ones((2,), bool)
+    dec = eng._decode.lower(eng.params, eng.tokens, eng.cache,
+                            mask).compile()
+    assert want <= _scopes_in(dec)
+    toks = {"tokens": jax.numpy.ones((1, 8), jax.numpy.int32)}
+    slot = jax.numpy.int32(0)
+    for fn, name in ((eng._chunk_first, "prefill_chunk_first"),
+                     (eng._chunk, "prefill_chunk")):
+        lowered = fn.lower(eng.params, toks, eng.cache, slot)
+        assert f"jit_{name}" in lowered.as_text()
+        assert want <= _scopes_in(lowered.compile())
+
+
+def test_expert_parallel_program_names_its_exchange():
+    """On a mesh the MoE layer's collectives run under ``moe/exchange``
+    (read from the lowered program: one device's exchange compiles away)."""
+    from repro.launch.mesh import make_ep_mesh
+    from repro.serving import DistributedEngine
+
+    cfg, model, params = _model("phi3.5-moe-42b-a6.6b")
+    mesh = make_ep_mesh(1)
+    eng = DistributedEngine(model, params, 2, 32, mesh=mesh, moe_impl="ep",
+                            config=EngineConfig(prefill_len=8))
+    mask = jax.numpy.ones((2,), bool)
+    with jax.set_mesh(mesh):
+        dec = jax.jit(eng.model.decode_step).lower(
+            eng.params, eng.tokens, eng.cache, mask)
+    assert {"moe/router", "moe/dispatch", "moe/exchange", "moe/experts",
+            "moe/combine"} <= _scopes_in(dec)
