@@ -43,11 +43,11 @@ import time
 import numpy as np
 
 ARCH = "phi3.5-moe-42b-a6.6b"
-# Four of the 32 layers: the kernel path's decode program needs the weights
-# (10.93 GB at four layers, 2.6 GB more per layer) and the 0.27 GB cache as
-# arguments plus 3.06 GB of scratch (``compiled.memory_analysis()`` against
-# a described v5e): 14.3 GB. A fifth layer would need 16.9 GB, the whole of
-# the chip's 16 GiB.
+# Four of the 32 layers, the benchmark's cut: the kernel path's decode
+# program needs the weights (10.93 GB at four layers, 2.6 GB more per
+# layer) and the 0.27 GB cache as arguments plus 0.34 GB of scratch
+# (``compiled.memory_analysis()`` against a described v5e): 11.5 GB. With
+# a fifth layer it would need 14.3 GB.
 N_LAYERS = 4
 SLOTS = 8
 CACHE_CAP = 2048

@@ -17,14 +17,23 @@ w_gate/w_up (d,bf), w_down (bf,d) and the output block, each double
 buffered, plus the (bc,d) f32 accumulator — about 12 MiB at bc=bf=128,
 d=4096 in bf16. Every matmul dim is a multiple of 128 for the MXU.
 
+**Stacked weights** (``layer``): the weights may carry a leading layer
+axis, (L, E, d, f) / (L, E, f, d), as a model's layer stack stores them.
+The layer index and the (E,) group sizes are scalar-prefetch operands
+(``PrefetchScalarGridSpec``), so they sit in SMEM before the grid runs;
+the weight index maps return ``(layer, e, 0, f)`` / ``(layer, e, f, 0)``
+and the pipeline DMAs each (d, bf) / (bf, d) block of that layer straight
+from the stack in HBM. A layer scan hands the kernel the whole stack and
+its index, so no per-layer copy of the experts' weights exists (XLA
+cannot fuse a slice into a custom call's operand, so slicing first would
+materialize one). Weights of one layer are the stack with L = 1.
+
 **Ragged groups** (``group_sizes``): the serving dispatch path routes only a
 handful of real tokens per step, so most capacity rows are zero padding.
-The whole (E,) row-count array rides in SMEM, indexed by the expert grid
-index (the TPU compiler refuses a rank-1 ``(1,)`` SMEM block), and every
-(e, c)-block whose row range starts at or beyond its group's fill level
-skips all three matmuls — the MegaBlocks-style dropless-group idea at
-block granularity. Skipped blocks flush the zero accumulator, which equals
-the dense result exactly: padding rows are zero and FFN(0) == 0.
+Every (e, c)-block whose row range starts at or beyond its group's fill
+level skips all three matmuls — the MegaBlocks-style dropless-group idea
+at block granularity. Skipped blocks flush the zero accumulator, which
+equals the dense result exactly: padding rows are zero and FFN(0) == 0.
 
 Validated against ``ref.moe_ffn_ref`` in interpret mode, compiled for a
 described TPU v5e at phi3.5-moe widths (E=16, d=4096, f=6400; capacity 8
@@ -56,8 +65,9 @@ def align_capacity(cap: int, block_c: int) -> int:
     return -(-cap // block_c) * block_c
 
 
-def _kernel(gs_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref, *,
-            act: str, n_f: int, block_c: int):
+def _kernel(layer_ref, gs_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref,
+            *, act: str, n_f: int, block_c: int):
+    del layer_ref                    # read by the weight index maps only
     f_idx = pl.program_id(2)
 
     @pl.when(f_idx == 0)
@@ -73,11 +83,11 @@ def _kernel(gs_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref, *,
     @pl.when(live)
     def _compute():
         x = x_ref[0]                               # (bc, d)
-        hg = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
-        hu = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+        hg = jnp.dot(x, wg_ref[0, 0], preferred_element_type=jnp.float32)
+        hu = jnp.dot(x, wu_ref[0, 0], preferred_element_type=jnp.float32)
         act_fn = jax.nn.gelu if act == "geglu" else jax.nn.silu
         h = (act_fn(hg) * hu).astype(x.dtype)      # (bc, bf)
-        acc_ref[...] += jnp.dot(h, wd_ref[0],
+        acc_ref[...] += jnp.dot(h, wd_ref[0, 0],
                                 preferred_element_type=jnp.float32)
 
     @pl.when(f_idx == n_f - 1)
@@ -87,12 +97,15 @@ def _kernel(gs_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref, acc_ref, *,
 
 @functools.partial(jax.jit, static_argnames=("act", "block_c", "block_f",
                                              "interpret"))
-def moe_gmm(x, w_gate, w_up, w_down, *, group_sizes=None, act: str = "swiglu",
-            block_c: int = 128, block_f: int = 128,
+def moe_gmm(x, w_gate, w_up, w_down, *, layer=None, group_sizes=None,
+            act: str = "swiglu", block_c: int = 128, block_f: int = 128,
             interpret: bool = False):
     """Fused grouped expert FFN.
 
-    x: (E, C, d); w_gate/w_up: (E, d, f); w_down: (E, f, d) → (E, C, d).
+    x: (E, C, d); w_gate/w_up: (L, E, d, f); w_down: (L, E, f, d) →
+    (E, C, d), with the experts of layer ``layer`` (a traced int32
+    scalar). Weights of one layer, (E, d, f) / (E, f, d) with
+    ``layer=None``, are the stack with L = 1.
     C and f must be divisible by the block sizes (``align_capacity`` gives a
     compliant C; ``ops.moe_ffn`` derives a legal f block).
 
@@ -100,6 +113,11 @@ def moe_gmm(x, w_gate, w_up, w_down, *, group_sizes=None, act: str = "swiglu",
     blocks past a group's fill level are skipped (flushed as zeros). None
     runs every block (the dense all-to-all layout).
     """
+    if w_gate.ndim == 3:
+        if layer is not None:
+            raise ValueError("layer given for weights of one layer")
+        w_gate, w_up, w_down = w_gate[None], w_up[None], w_down[None]
+        layer = 0
     e, c, d = x.shape
     f = w_gate.shape[-1]
     bc = min(block_c, c)
@@ -109,22 +127,29 @@ def moe_gmm(x, w_gate, w_up, w_down, *, group_sizes=None, act: str = "swiglu",
     if group_sizes is None:
         group_sizes = jnp.full((e,), c, jnp.int32)
     group_sizes = group_sizes.astype(jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     n_f = f // bf
-    grid = (e, c // bc, n_f)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,                     # layer, group_sizes
+        grid=(e, c // bc, n_f),
+        in_specs=[
+            pl.BlockSpec((1, bc, d), lambda e_, c_, f_, l, g: (e_, c_, 0)),
+            pl.BlockSpec((1, 1, d, bf),
+                         lambda e_, c_, f_, l, g: (l[0], e_, 0, f_)),
+            pl.BlockSpec((1, 1, d, bf),
+                         lambda e_, c_, f_, l, g: (l[0], e_, 0, f_)),
+            pl.BlockSpec((1, 1, bf, d),
+                         lambda e_, c_, f_, l, g: (l[0], e_, f_, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, bc, d),
+                               lambda e_, c_, f_, l, g: (e_, c_, 0)),
+        scratch_shapes=[pltpu.VMEM((bc, d), jnp.float32)],
+    )
     return pl.pallas_call(
         functools.partial(_kernel, act=act, n_f=n_f, block_c=bc),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),     # whole (E,) array
-            pl.BlockSpec((1, bc, d), lambda e_, c_, f_: (e_, c_, 0)),
-            pl.BlockSpec((1, d, bf), lambda e_, c_, f_: (e_, 0, f_)),
-            pl.BlockSpec((1, d, bf), lambda e_, c_, f_: (e_, 0, f_)),
-            pl.BlockSpec((1, bf, d), lambda e_, c_, f_: (e_, f_, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bc, d), lambda e_, c_, f_: (e_, c_, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((e, c, d), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bc, d), jnp.float32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(group_sizes, x, w_gate, w_up, w_down)
+    )(layer, group_sizes, x, w_gate, w_up, w_down)

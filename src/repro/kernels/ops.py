@@ -54,17 +54,20 @@ def _divisor_block(n: int, block: int, align: int) -> int:
 
 def moe_ffn(x, w_gate, w_up, w_down, act: str = "swiglu",
             impl: str = "auto", interpret: bool | None = None,
-            group_sizes=None, block_c: int = 128, block_f: int = 128):
+            group_sizes=None, block_c: int = 128, block_f: int = 128,
+            layer=None):
     """Grouped expert FFN: Pallas on TPU, reference elsewhere.
 
     ``group_sizes`` (E,) enables the ragged path: expert blocks past the
     fill level are skipped on the kernel and zero-masked on the reference —
     identical semantics (zero-padded buckets, FFN(0) == 0).
+    ``layer``: the weights are a layer stack (L, E, ...) and this traced
+    index picks the layer; the kernel reads its blocks from the stack.
     """
     if impl == "ref" or (impl == "auto" and not use_pallas(interpret)):
         return ref.moe_ffn_ref(x, w_gate, w_up, w_down, act,
-                               group_sizes=group_sizes)
-    return moe_gmm(x, w_gate, w_up, w_down, act=act,
+                               group_sizes=group_sizes, layer=layer)
+    return moe_gmm(x, w_gate, w_up, w_down, act=act, layer=layer,
                    group_sizes=group_sizes,
                    block_c=_divisor_block(x.shape[1], block_c, 8),
                    block_f=_divisor_block(w_gate.shape[-1], block_f, 128),

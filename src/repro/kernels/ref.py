@@ -7,14 +7,20 @@ import jax.numpy as jnp
 
 
 def moe_ffn_ref(x, w_gate, w_up, w_down, act: str = "swiglu",
-                group_sizes=None):
+                group_sizes=None, layer=None):
     """Grouped expert FFN over capacity buckets.
 
     x: (E, C, d); w_gate/w_up: (E, d, f); w_down: (E, f, d) → (E, C, d).
     ``group_sizes``: optional (E,) real-row counts — rows at or beyond a
     group's fill level are zeroed, matching the ragged kernel's block-skip
     semantics exactly (pad rows are zero inputs, and FFN(0) == 0).
+    ``layer``: the weights are a layer stack (L, E, ...); use layer
+    ``layer`` of it (the slice fuses into the einsums that read it).
     """
+    if layer is not None:
+        w_gate, w_up, w_down = (
+            jax.lax.dynamic_index_in_dim(w, layer, keepdims=False)
+            for w in (w_gate, w_up, w_down))
     act_fn = jax.nn.gelu if act == "geglu" else jax.nn.silu
     h = act_fn(jnp.einsum("ecd,edf->ecf", x, w_gate,
                           preferred_element_type=jnp.float32))

@@ -105,6 +105,13 @@ def _is_experts_leaf(path) -> bool:
     return "experts" in names
 
 
+def _layer_of(tree, layer):
+    """Layer ``layer`` (traced) of a layer-stacked pytree."""
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False),
+        tree)
+
+
 def replicate_moe_params(params, spec: ReplicationSpec, axis: int = 1):
     """Widen every MoE layer's expert leaves to ``spec.n_phys`` physical
     experts (replicas are gathered copies). Full-model stacked-segment
@@ -411,9 +418,14 @@ def _shared_and_counts(p, xt, y, idx, shape, aux, moe, act, pc,
 
 def moe_apply_kernel(p, x, moe, act: str,
                      pc: ParallelContext = NO_PARALLEL,
-                     return_counts: bool = False):
+                     return_counts: bool = False, layer=None):
     """Kernelized MoE layer: same routing/capacity semantics as the dense
     reference, different machinery. x: (..., d) → (y, aux[, counts]).
+
+    ``layer``: ``p["experts"]`` leaves are a layer stack (L, E, ...) and
+    this traced index picks the layer. ``moe_gmm`` reads the layer's
+    blocks from the stack; the pure-jnp backends slice it where XLA ops
+    consume it, so the slice fuses.
 
     Dispatch is the sort-based ragged layout (``sort_dispatch``); compute is
     one of three statically-chosen backends:
@@ -473,13 +485,14 @@ def moe_apply_kernel(p, x, moe, act: str,
         with jax.named_scope("moe/dispatch"):
             xg = xt[t_f]                                 # (T*k, d)
         with jax.named_scope("moe/experts"):
-            hg = jnp.einsum("rd,rdf->rf", xg, experts["w_gate"][home_f],
+            w = experts if layer is None else _layer_of(experts, layer)
+            hg = jnp.einsum("rd,rdf->rf", xg, w["w_gate"][home_f],
                             preferred_element_type=jnp.float32)
-            hu = jnp.einsum("rd,rdf->rf", xg, experts["w_up"][home_f],
+            hu = jnp.einsum("rd,rdf->rf", xg, w["w_up"][home_f],
                             preferred_element_type=jnp.float32)
             act_fn = jax.nn.gelu if act == "geglu" else jax.nn.silu
             h = (act_fn(hg) * hu).astype(xt.dtype)
-            picked = jnp.einsum("rf,rfd->rd", h, experts["w_down"][home_f],
+            picked = jnp.einsum("rf,rfd->rd", h, w["w_down"][home_f],
                                 preferred_element_type=jnp.float32
                                 ).astype(xt.dtype)       # (T*k, d)
     else:
@@ -513,7 +526,7 @@ def moe_apply_kernel(p, x, moe, act: str,
             out_buf = kops.moe_ffn(
                 buf, experts["w_gate"], experts["w_up"], experts["w_down"],
                 act=act, interpret=kc.interpret,
-                group_sizes=group_sizes,
+                group_sizes=group_sizes, layer=layer,
                 block_c=kc.block_c, block_f=kc.block_f)
         with jax.named_scope("moe/combine"):
             flat_out = out_buf.reshape(n_phys * cap_pad, d)
@@ -572,10 +585,18 @@ def moe_apply_ep(p, x, moe, act: str, pc: ParallelContext,
 
 
 def moe_apply(p, x, moe, act: str, pc: ParallelContext = NO_PARALLEL,
-              return_counts: bool = False):
-    if pc.moe_impl in ("ep", "aurora") and pc.ep_axes:
-        return moe_apply_ep(p, x, moe, act, pc, return_counts=return_counts)
+              return_counts: bool = False, layer=None):
+    """The MoE layer, by ``pc.moe_impl``. ``layer`` (a serving scan's
+    block index) says that ``p["experts"]`` leaves are still the layer
+    stack (L, E, ...): the kernel path reads it in place, and the dense
+    and expert-parallel paths, whose XLA dots fuse the slice, slice it
+    here."""
     if pc.moe_impl == "kernel":
         return moe_apply_kernel(p, x, moe, act, pc,
-                                return_counts=return_counts)
+                                return_counts=return_counts, layer=layer)
+    if layer is not None:
+        with jax.named_scope("layer_weights"):
+            p = dict(p, experts=_layer_of(p["experts"], layer))
+    if pc.moe_impl in ("ep", "aurora") and pc.ep_axes:
+        return moe_apply_ep(p, x, moe, act, pc, return_counts=return_counts)
     return moe_apply_dense(p, x, moe, act, pc, return_counts=return_counts)

@@ -17,7 +17,9 @@ Layer kinds:
 Device work is named by ``jax.named_scope`` so a profiler trace can group
 it: ``layer_cache`` (a serving scan's own work: each block's cache sliced
 from the stacked cache and its new cache stacked back), ``layer_weights``
-(a serving block's weights sliced from the stack), ``attn`` with
+(a serving block's weights sliced from the stack; the MoE expert stacks
+only for the dense and expert-parallel paths, since the kernel path reads
+its layer's experts from the stack), ``attn`` with
 ``attn/cache_write`` inside it, the MoE layer's ``moe/*`` (``models.moe``)
 and ``lm_head``. A trace names an op by the innermost of these.
 """
@@ -34,7 +36,7 @@ import jax.numpy as jnp
 from . import attention as attn_mod
 from . import ssm as ssm_mod
 from .layers import NO_PARALLEL, ParallelContext, ffn_apply, init_ffn, rmsnorm
-from .moe import init_moe, moe_apply
+from .moe import _is_experts_leaf, init_moe, moe_apply
 
 
 # ---------------------------------------------------------------------------
@@ -230,8 +232,12 @@ def slice_cache_slot(cache, slot):
 # ---------------------------------------------------------------------------
 
 def _apply_layer(kind, p, x, entry, *, cfg, pc, mode, pos, pos3, length,
-                 shared, enc_out=None, collect_stats=False, row_mask=None):
+                 shared, enc_out=None, collect_stats=False, row_mask=None,
+                 layer=None):
     """One layer. Returns (x, new_cache_entry, aux, moe_counts).
+
+    ``layer``: a serving scan's block index; an MoE layer's expert leaves
+    then still hold the whole stack (``_run_segment``).
 
     ``moe_counts`` is None unless ``collect_stats`` and the layer is MoE, in
     which case it is a (B, S, E) float32 per-position count of routed
@@ -306,9 +312,11 @@ def _apply_layer(kind, p, x, entry, *, cfg, pc, mode, pos, pos3, length,
     if kind == "E":
         if collect_stats:
             y2, aux, counts = moe_apply(p["moe"], h2, cfg.moe, cfg.act, pc,
-                                        return_counts=True)   # (B, S, E)
+                                        return_counts=True,
+                                        layer=layer)          # (B, S, E)
         else:
-            y2, aux = moe_apply(p["moe"], h2, cfg.moe, cfg.act, pc)
+            y2, aux = moe_apply(p["moe"], h2, cfg.moe, cfg.act, pc,
+                                layer=layer)
     else:
         y2 = ffn_apply(pp["ffn"], h2, cfg.act, pc)
     with jax.named_scope("attn/cache_write"):
@@ -327,23 +335,29 @@ def _run_segment(seg, seg_params, seg_cache, x, *, cfg, pc, mode, pos, pos3,
 
     Serving (a cache is present) scans block indices and slices each
     block's weights from the stack itself, under the ``layer_weights``
-    scope, so the copies the device makes of them are named in a trace;
-    the scan runs under ``layer_cache``, which names the slices of the
-    stacked cache it feeds each block and the stacking of the new cache
-    it returns. Training scans the stack as ``xs``: a closed-over stack
-    would make the backward pass add a full-size cotangent in every
-    iteration."""
+    scope, so any copies the device makes of them are named in a trace.
+    The MoE layers' expert leaves are the exception: they stay whole and
+    the block index goes down with them (``moe_apply(layer=)``), so the
+    Pallas ``moe_gmm`` reads the layer's blocks from the stack; a slice
+    fed to it would be a copy, since XLA cannot fuse a slice into a
+    custom call. The scan runs under ``layer_cache``, which names the
+    slices of the stacked cache it feeds each block and the stacking of
+    the new cache it returns. Training scans the stack as ``xs``: a
+    closed-over stack would make the backward pass add a full-size
+    cotangent in every iteration."""
     with_cache = mode != "train"
 
     def block_weights(i):
         with jax.named_scope("layer_weights"):
-            return jax.tree.map(
-                lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
+            return jax.tree_util.tree_map_with_path(
+                lambda path, a: a if _is_experts_leaf(path) else
+                jax.lax.dynamic_index_in_dim(a, i, keepdims=False),
                 seg_params)
 
     def block(carry, xs):
         x, aux = carry
-        params = block_weights(xs[0]) if with_cache else xs
+        layer = xs[0] if with_cache else None
+        params = block_weights(layer) if with_cache else xs
         cache = xs[1] if with_cache else (None,) * len(seg.kinds)
         new_entries = []
         stats = []
@@ -352,7 +366,7 @@ def _run_segment(seg, seg_params, seg_cache, x, *, cfg, pc, mode, pos, pos3,
                 kind, params[i], x, cache[i], cfg=cfg, pc=pc, mode=mode,
                 pos=pos, pos3=pos3, length=length, shared=shared,
                 enc_out=enc_out, collect_stats=collect_stats,
-                row_mask=row_mask)
+                row_mask=row_mask, layer=layer)
             aux = aux + a
             new_entries.append(nc)
             if cnt is not None:

@@ -223,6 +223,50 @@ def test_continuous_engine_kernel_tokens_and_logits():
         np.asarray(jnp.argmax(lk[:, :, :cfg.vocab], -1)))
 
 
+@pytest.mark.parametrize("replication", [None, (2, 1, 1, 1)])
+def test_serving_scan_feeds_expert_stack_token_identity(monkeypatch,
+                                                        replication):
+    """The serving scan hands ``moe_gmm`` each MoE layer's expert stack and
+    the block index, where it used to slice the block's experts first; the
+    kernel reads the same bytes, so greedy decoding through the Pallas
+    bodies (interpret mode) emits the same tokens either way, with and
+    without replicated experts (which widen axis 1 of the stack)."""
+    from repro.models import moe as moe_mod
+    from repro.models import transformer as tf
+
+    cfg, model, params = _model()
+    assert cfg.n_layers > 1                  # the block index picks a layer
+    seen = []
+
+    def serve():
+        jax.clear_caches()                   # retrace under the patches
+        eng = ContinuousEngine(
+            model, params, 2, 32,
+            config=EngineConfig(prefill_len=8,
+                                kernels=KernelConfig(interpret=True)))
+        if replication is not None:
+            eng.adopt_replication(replication)
+        out = eng.serve(_requests(4, seed=3, max_new=5, vocab=cfg.vocab))
+        return [r.out_tokens for r in out]
+
+    def spy(*a, layer=None, **k):
+        seen.append(layer is not None)
+        return moe_mod.moe_apply(*a, layer=layer, **k)
+
+    monkeypatch.setattr(tf, "moe_apply", spy)
+    got = serve()
+    assert seen and all(seen)                # stacks and indices reached it
+    # The scan as it was: every leaf sliced per block, one layer's experts
+    # handed to the kernel.
+    seen.clear()
+    monkeypatch.setattr(tf, "_is_experts_leaf", lambda path: False)
+    monkeypatch.setattr(tf, "moe_apply",
+                        lambda *a, layer=None, **k: spy(*a, **k))
+    want = serve()
+    assert seen and not any(seen)
+    assert all(want) and got == want
+
+
 def test_kernel_engine_monitor_counts_match_dense():
     """Routing counts harvested on the kernel path equal the dense path's —
     the re-planner sees the same traffic either way."""

@@ -80,6 +80,39 @@ def test_moe_gmm_block_sweep(block):
     np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("layer", [0, 1, 2, None])
+def test_moe_gmm_reads_layer_of_stack(layer):
+    """Weights stacked by layer (L = 3, a layer scan's stack): the kernel
+    reads layer ``layer``'s blocks in place and equals the kernel and the
+    reference on that layer's slice, over ragged groups (one empty, one
+    partial, one full). ``layer=None`` passes one layer's 3-D weights, as
+    callers outside a layer scan do."""
+    n_layers, e, c, d, f = 3, 3, 256, 64, 256
+    ks = jax.random.split(jax.random.PRNGKey(7), 4)
+    gs = jnp.asarray([0, 37, 256], jnp.int32)
+    live = jnp.arange(c)[None, :] < gs[:, None]
+    x = jnp.where(live[..., None],
+                  jax.random.normal(ks[0], (e, c, d), jnp.float32), 0.0)
+    wg = jax.random.normal(ks[1], (n_layers, e, d, f)) * d ** -0.5
+    wu = jax.random.normal(ks[2], (n_layers, e, d, f)) * d ** -0.5
+    wd = jax.random.normal(ks[3], (n_layers, e, f, d)) * f ** -0.5
+    if layer is None:
+        stack, one = (wg[1], wu[1], wd[1]), (wg[1], wu[1], wd[1])
+    else:
+        stack, one = (wg, wu, wd), (wg[layer], wu[layer], wd[layer])
+        layer = jnp.int32(layer)                     # traced, as in a scan
+    got = moe_gmm(x, *stack, layer=layer, group_sizes=gs, block_c=64,
+                  interpret=True)
+    sliced = moe_gmm(x, *one, group_sizes=gs, block_c=64, interpret=True)
+    want = ref.moe_ffn_ref(x, *one, "swiglu", group_sizes=gs)
+    np.testing.assert_array_equal(got, sliced)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        ref.moe_ffn_ref(x, *stack, "swiglu", group_sizes=gs, layer=layer),
+        want, rtol=0, atol=0)
+    assert not np.asarray(got[0]).any()              # the empty group
+
+
 @pytest.mark.parametrize("b,h,hkv,s,d", [
     (2, 8, 8, 512, 64),      # MHA
     (2, 8, 2, 1024, 64),     # GQA 4:1

@@ -74,6 +74,56 @@ def test_moe_gmm_compiles_at_phi35_widths(one_chip, cap):
     assert _kernels_in(compiled) == 1
 
 
+@pytest.mark.parametrize("cap", [8, 256])
+def test_moe_gmm_compiles_on_layer_stack(one_chip, cap):
+    """A serving scan hands the kernel the whole (L, E, ...) expert stack
+    and a traced layer index: one kernel, no slice of the stack."""
+    kc = KernelConfig()
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    fn = functools.partial(moe_gmm, block_c=_divisor_block(cap, kc.block_c, 8),
+                           block_f=_divisor_block(D_FF, kc.block_f, 128))
+    compiled = jax.jit(
+        lambda x, wg, wu, wd, gs, i: fn(x, wg, wu, wd, layer=i,
+                                        group_sizes=gs)).lower(
+        sds((E, cap, D_MODEL), BF16), sds((4, E, D_MODEL, D_FF), BF16),
+        sds((4, E, D_MODEL, D_FF), BF16), sds((4, E, D_FF, D_MODEL), BF16),
+        sds((E,), jnp.int32), sds((), jnp.int32)).compile()
+    assert _kernels_in(compiled) == 1
+    assert f"bf16[{E},{D_MODEL},{D_FF}]" not in compiled.as_text()
+
+
+def test_kernel_decode_step_holds_no_expert_copy(one_chip, monkeypatch):
+    """The kernel-path decode program of a four-layer phi3.5-moe cut at
+    published widths: ``moe_gmm`` reads each layer's experts from the
+    stacked parameters, so no one-layer expert array (16 x 4096 x 6400,
+    839 MB in bf16) exists anywhere in the compiled program."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models import Model
+
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)   # compile, not run
+    cfg = dataclasses.replace(get_config("phi3.5-moe-42b-a6.6b"), n_layers=4)
+    model = Model(cfg).with_kernels(KernelConfig())
+
+    def shapes(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = shapes(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    cache = shapes(jax.eval_shape(
+        lambda: model.init_cache(B, S, per_slot_len=True)))
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    compiled = jax.jit(model.decode_step, donate_argnums=(2,)).lower(
+        params, sds((B, 1), jnp.int32), cache, sds((B,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert _kernels_in(compiled) == 2                  # moe_gmm, decode_attn
+    for one_layer in (f"bf16[{E},{D_MODEL},{D_FF}]",
+                      f"bf16[{E},{D_FF},{D_MODEL}]"):
+        assert one_layer not in text
+
+
 def test_decode_attn_compiles_at_phi35_widths(one_chip):
     block_s = _divisor_block(S, KernelConfig().block_s, 8)
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
