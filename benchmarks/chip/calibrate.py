@@ -33,10 +33,11 @@ from benchmarks.chip import run  # noqa: E402
 
 
 def _offered_tok_per_s(cell, seed, seconds):
-    from benchmarks.chip import traffic
+    from benchmarks.chip import arch, traffic
 
+    cfg = cell["cfg"]
     win = [o for o in traffic.offered(cell["mix"], seed, seconds,
-                                      cell["cfg"]["vocab_size"])
+                                      arch.find(cfg).vocab(cfg))
            if o.in_window]
     return sum(o.out_len for o in win) / seconds
 

@@ -1,5 +1,6 @@
 """Whether what the timed path served is correct: a comparison with the
-float32 reference (``reference.py``) of a sample of finished requests.
+architecture's float32 reference (``arch/<module>.py``, compared by
+``reference.served_gaps``) of a sample of finished requests.
 
 The number compared is the widest gap, over the served tokens, by which
 a served token's reference logit lies below the reference's best logit
@@ -20,7 +21,8 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmarks.chip.reference import Reference, served_gaps
+from benchmarks.chip import arch
+from benchmarks.chip.reference import served_gaps
 
 
 def sample(done: list, seed: int, tokens: int, slots: int) -> list:
@@ -57,7 +59,7 @@ def padded_prompt(prompt, padded: int) -> np.ndarray:
 def readings(cfg: dict, params, inputs, control=False) -> dict:
     """Widest served-token gap over the positions kept, and how many were
     kept and left out; with ``control`` also the fp8 control's gap."""
-    ref = Reference(cfg, params)
+    ref = arch.find(cfg).Reference(cfg, params)
     tie = cfg["check"]["tie_margin"]
     out = {}
     gaps, margin = served_gaps(ref, inputs)

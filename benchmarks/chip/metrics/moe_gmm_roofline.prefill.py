@@ -2,12 +2,12 @@
 real prompt rows and the experts they hit, over the kernel's trace
 time, %."""
 
-from benchmarks.chip import reading
+from benchmarks.chip import arch, reading
 
 
 def read(ctx):
     fl = by = 0.0
-    n = ctx.cfg["num_hidden_layers"]
+    n = arch.find(ctx.cfg).moe_layers(ctx.cfg)
     for s in ctx.traced_steps():
         if s.chunk_real:
             f, b = reading.moe_prefill(ctx, s)

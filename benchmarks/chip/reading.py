@@ -7,7 +7,7 @@ of every step and request (``run.Context``).
 
 from __future__ import annotations
 
-from benchmarks.chip import work, xtrace
+from benchmarks.chip import arch, work, xtrace
 
 DECODE = ("decode_step",)
 # The engine jits the chunk program from a ``functools.partial``, which
@@ -47,29 +47,36 @@ def roofline(ctx, flops: float, nbytes: float, seconds: float | None):
     return 100.0 * t_min / seconds
 
 
-def decode_work(ctx, layer_fn):
-    """Sum over traced decode steps and layers of ``layer_fn(step)``'s
-    (FLOPs, bytes)."""
+def decode_work(ctx, layer_fn, layers: int):
+    """Sum over traced decode steps of ``layers`` times ``layer_fn(step)``'s
+    (FLOPs, bytes): the work of one layer of a kind, over the ``layers``
+    of that kind (``attn_layers`` or ``moe_layers`` of the
+    architecture's module)."""
     fl = by = 0.0
-    n_layers = ctx.cfg["num_hidden_layers"]
     for s in ctx.traced_steps():
         if s.decode_active:
             f, b = layer_fn(s)
-            fl += n_layers * f
-            by += n_layers * b
+            fl += layers * f
+            by += layers * b
     return fl, by
 
 
+def _moe_rows(cfg, tokens: int):
+    """The expert FFN's work for ``tokens`` tokens in ONE MoE layer: their
+    routed pairs that land on the experts held here (each token takes k of
+    the experts routed over, spread evenly) and the distinct held experts
+    those pairs hit."""
+    held, routed, k = arch.find(cfg).experts(cfg)
+    pairs = tokens * k * held / routed
+    return work.moe_ffn(cfg, pairs, work.expected_distinct(pairs, held))
+
+
 def moe_decode(ctx, s):
-    k, e = ctx.cfg["num_experts_per_tok"], ctx.cfg["num_local_experts"]
-    pairs = s.decode_active * k
-    return work.moe_ffn(ctx.cfg, pairs, work.expected_distinct(pairs, e))
+    return _moe_rows(ctx.cfg, s.decode_active)
 
 
 def moe_prefill(ctx, s):
-    k, e = ctx.cfg["num_experts_per_tok"], ctx.cfg["num_local_experts"]
-    pairs = s.chunk_real * k
-    return work.moe_ffn(ctx.cfg, pairs, work.expected_distinct(pairs, e))
+    return _moe_rows(ctx.cfg, s.chunk_real)
 
 
 def mfu(ctx, flops: float, programs) -> float | None:

@@ -7,7 +7,10 @@ A cell (``workloads`` in ``BENCHMARK.json``) names a configuration
 (``configs/<config>.json``: published sizes, the cut, slots and cache)
 and a traffic mix (``traffic/<mix>.json``: lengths, rate, lead-in). The
 harness finds both, and each per-layer metric (``metrics/<name>.py``), by
-name; adding a cell, a configuration, a mix or a metric adds files only.
+name, and the configuration's architecture (``arch/<module>.py``: the
+program's configuration, weights, reference and work counts) by its
+``arch``; adding a cell, a configuration, an architecture, a mix or a
+metric adds files only.
 
 One run, in order:
 
@@ -57,7 +60,7 @@ for _p in (str(ROOT / "src"), str(ROOT)):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-from benchmarks.chip import check, traffic, weights, xtrace  # noqa: E402
+from benchmarks.chip import arch, check, traffic, weights, xtrace  # noqa: E402
 from benchmarks.chip.peaks import UnknownDevice, peaks  # noqa: E402
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
@@ -116,24 +119,6 @@ def load_metric(name: str):
 # The system under test
 # ---------------------------------------------------------------------------
 
-def program_config(cfg: dict):
-    """The engine's ``ModelConfig`` for the sizes in ``cfg``."""
-    from repro.configs import get_config
-
-    base = get_config(cfg["arch"])
-    moe = dataclasses.replace(
-        base.moe, n_experts=cfg["num_local_experts"],
-        top_k=cfg["num_experts_per_tok"], d_ff=cfg["intermediate_size"],
-        capacity_factor=cfg["capacity_factor"])
-    return dataclasses.replace(
-        base, n_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        vocab=cfg["vocab_size"], n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        head_dim=cfg["hidden_size"] // cfg["num_attention_heads"],
-        rope_theta=cfg["rope_theta"], norm_eps=cfg["rms_norm_eps"],
-        dtype=cfg["torch_dtype"], moe=moe)
-
-
 def build(cfg: dict, chips: int, seed: int, devices, log=lambda m: None):
     """Weights and the engine a user would build for this deployment."""
     import jax
@@ -142,7 +127,7 @@ def build(cfg: dict, chips: int, seed: int, devices, log=lambda m: None):
     from repro.models import Model
     from repro.serving import ContinuousEngine, DistributedEngine, EngineConfig
 
-    pcfg = program_config(cfg)
+    pcfg = arch.find(cfg).program_config(cfg)
     model = Model(pcfg)
     expect = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     got = jax.eval_shape(lambda: weights.generate(cfg, seed))
@@ -403,7 +388,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
 
     from repro.serving import Request
 
-    offered = traffic.offered(mix, seed, seconds, cfg["vocab_size"])
+    vocab = arch.find(cfg).vocab(cfg)
+    offered = traffic.offered(mix, seed, seconds, vocab)
     tracks = []
     for o in offered:
         p = traffic.padded(len(o.prompt), step_len)
@@ -423,8 +409,8 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
     # Warm every program the traffic uses: a first chunk, a later chunk,
     # decode, and the admission's small ops.
     rng = np.random.default_rng(seed + 1)
-    warm = [Request(prompt=rng.integers(1, cfg["vocab_size"], n,
-                                        dtype=np.int32), max_new_tokens=3)
+    warm = [Request(prompt=rng.integers(1, vocab, n, dtype=np.int32),
+                    max_new_tokens=3)
             for n in (step_len + 1, step_len // 2)]
     for r in warm:
         eng.submit(r)
@@ -591,7 +577,9 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, *,
 
 def host_steps(steps: list, seconds: float) -> None:
     """How long the window's engine steps took on the host clock, those
-    with a prefill chunk apart from those without."""
+    with a prefill chunk apart from those without; and the slots and
+    valid cache positions a decode step served, in the window and in its
+    traced part (the work the decode readers count)."""
     win = [s for s in steps if 0.0 <= s.t0 and s.t1 <= seconds]
     for label, part in (("decode only", [s for s in win if not s.ran_chunk]),
                         ("with a chunk", [s for s in win if s.ran_chunk])):
@@ -601,6 +589,14 @@ def host_steps(steps: list, seconds: float) -> None:
                 f"{1000 * nearest_rank(d, 0.5):.3f} ms, p90 "
                 f"{1000 * nearest_rank(d, 0.9):.3f} ms, mean active "
                 f"{sum(s.decode_active for s in part) / len(d):.2f}")
+    for label, part in (("window", win),
+                        ("traced", [s for s in steps if s.traced])):
+        dec = [s for s in part if s.decode_active]
+        if dec:
+            log(f"decode steps ({label}): {len(dec)}, mean active slots "
+                f"{sum(s.decode_active for s in dec) / len(dec):.3f}, mean "
+                f"valid positions "
+                f"{sum(s.decode_valid for s in dec) / len(dec):.1f}")
 
 
 def end_to_end(ctx: Context, wanted: list, setup_s: float) -> dict:

@@ -1,26 +1,30 @@
-"""A cell at CPU size for the tests: the phi3.5-moe configuration file
-and a traffic mix with every width and length cut small, run with the
-same harness. Its check limits are its own (the chip cells' limits are
-set from chip readings in ``PERF.md``)."""
+"""A cell at CPU size for the tests: a configuration file cut small by its
+architecture's module (``arch/<module>.py``, ``tiny``: every width and
+count, and the check limits set from readings at that size) and a traffic
+mix with every length cut small, run with the same harness. The chip
+cells' limits are set from chip readings in ``PERF.md``."""
 
 from __future__ import annotations
 
 import json
 from pathlib import Path
 
+from benchmarks.chip import arch
+
 HERE = Path(__file__).resolve().parent
 
 
-def cell(chips: int = 1, mix: str = "chat") -> dict:
-    cfg = json.loads((HERE / "configs" / "phi35moe-1chip.json").read_text())
-    cfg.update(hidden_size=256, intermediate_size=512, num_attention_heads=4,
-               num_key_value_heads=2, num_hidden_layers=2,
-               num_local_experts=4, vocab_size=512)
+def config() -> dict:
+    """``configs/phi35moe-1chip.json`` as filed."""
+    return json.loads((HERE / "configs" / "phi35moe-1chip.json").read_text())
+
+
+def cell(chips: int = 1, mix: str = "chat", cfg: dict | None = None) -> dict:
+    """The tiny cell of ``cfg`` (a configuration as its file holds it;
+    default ``configs/phi35moe-1chip.json``) under ``mix``."""
+    cfg = cfg or config()
+    cfg = arch.find(cfg).tiny(cfg)
     cfg["serving"] = {"slots": 4, "cache_capacity": 512, "prefill_chunk": 64}
-    # Readings at this size over seeds 1-6, 77 and 2**32 + 5: sound runs
-    # 0 to 0.0227, the fp8 control 0.085 to 1.19 (test_chipbench_check).
-    cfg["check"] = {"max_logit_gap": 0.045, "min_compared_tokens": 20,
-                    "tie_margin": 0.05}
     m = json.loads((HERE / "traffic" / f"{mix}.json").read_text())
     m.update(rate=3.0, lead_in_s=0.5, drain_s=60.0, trace_offset_s=0.2,
              trace_seconds=0.5, check_tokens=120,

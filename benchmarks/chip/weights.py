@@ -1,10 +1,11 @@
 """Random weights from the seed, made by the benchmark on the device in
 one jitted call, in the types they are served in, in the parameter layout
-the serving engine takes (stacked per layer: ``segments[0][0]``).
+the serving engine takes. The architecture's module (``arch/``) lists the
+leaves (``shapes``) and places them in that tree (``nest``).
 
-The same arrays feed the reference (``reference.py``) after the program's
-state is freed: the reference reads them leaf by leaf and takes nothing
-that the program made.
+The same arrays feed the reference (``arch/<module>.py``) after the
+program's state is freed: the reference reads them leaf by leaf and takes
+nothing that the program made.
 """
 
 from __future__ import annotations
@@ -12,7 +13,8 @@ from __future__ import annotations
 import math
 
 import jax
-import jax.numpy as jnp
+
+from benchmarks.chip import arch
 
 
 def prng_key(seed: int):
@@ -28,60 +30,22 @@ def padded_vocab(vocab: int) -> int:
     return -(-vocab // 256) * 256
 
 
-def shapes(cfg: dict) -> dict:
-    """{leaf path: (shape, dtype, scale)}: each weight is drawn with
-    standard deviation ``scale``; None marks a norm weight (served as
-    1 + w, drawn with deviation ``NORM_SCALE`` around 0)."""
-    d, h = cfg["hidden_size"], cfg["num_attention_heads"]
-    hkv, hd = cfg["num_key_value_heads"], cfg["hidden_size"] // h
-    f, e = cfg["intermediate_size"], cfg["num_local_experts"]
-    n, vp = cfg["num_hidden_layers"], padded_vocab(cfg["vocab_size"])
-    dt = jnp.dtype(cfg["torch_dtype"])
-    return {
-        "embed": ((vp, d), dt, 0.02),
-        "final_norm": ((d,), dt, None),
-        "lm_head": ((d, vp), dt, d ** -0.5),
-        "ln1": ((n, d), dt, None),
-        "ln2": ((n, d), dt, None),
-        "attn/wq": ((n, d, h, hd), dt, d ** -0.5),
-        "attn/wk": ((n, d, hkv, hd), dt, d ** -0.5),
-        "attn/wv": ((n, d, hkv, hd), dt, d ** -0.5),
-        "attn/wo": ((n, h, hd, d), dt, (h * hd) ** -0.5),
-        # The router is kept in float32, as the engine serves it.
-        "moe/router": ((n, d, e), jnp.dtype("float32"), d ** -0.5),
-        "moe/experts/w_gate": ((n, e, d, f), dt, d ** -0.5),
-        "moe/experts/w_up": ((n, e, d, f), dt, d ** -0.5),
-        "moe/experts/w_down": ((n, e, f, d), dt, f ** -0.5),
-    }
-
-
 NORM_SCALE = 0.1
 
 
-def _nest(flat: dict) -> dict:
-    layer: dict = {}
-    for path, x in flat.items():
-        if path in ("embed", "final_norm", "lm_head"):
-            continue
-        node = layer
-        *parents, leaf = path.split("/")
-        for p in parents:
-            node = node.setdefault(p, {})
-        node[leaf] = x
-    return {"embed": flat["embed"], "final_norm": flat["final_norm"],
-            "lm_head": flat["lm_head"], "segments": ((layer,),)}
-
-
 def generate(cfg: dict, seed: int):
-    """The whole parameter tree as one traceable function of the seed."""
+    """The whole parameter tree as one traceable function of the seed:
+    each leaf of ``shapes``, in its order, drawn from the seed folded with
+    its index."""
+    mod = arch.find(cfg)
     key = prng_key(seed)
     flat = {}
-    for i, (path, (shape, dt, scale)) in enumerate(shapes(cfg).items()):
+    for i, (path, (shape, dt, scale)) in enumerate(mod.shapes(cfg).items()):
         k = jax.random.fold_in(key, i)
         # Uniform with unit variance, drawn in the served type.
         a = 3 ** 0.5 * (NORM_SCALE if scale is None else scale)
         flat[path] = jax.random.uniform(k, shape, dt, -a, a)
-    return _nest(flat)
+    return mod.nest(flat)
 
 
 def make(cfg: dict, seed: int, shardings=None):
@@ -90,14 +54,12 @@ def make(cfg: dict, seed: int, shardings=None):
     return jax.jit(lambda: generate(cfg, seed), out_shardings=shardings)()
 
 
-def leaf(params, path: str):
-    """The stacked array at ``path`` (``shapes`` keys)."""
-    if path in ("embed", "final_norm", "lm_head"):
-        return params[path]
-    node = params["segments"][0][0]
-    for p in path.split("/"):
-        node = node[p]
-    return node
+def leaf(cfg: dict, params, path: str):
+    """The array at ``path`` (a ``shapes`` key), wherever the
+    architecture's ``nest`` puts it in ``params``."""
+    mod = arch.find(cfg)
+    names = jax.tree.leaves(mod.nest({p: p for p in mod.shapes(cfg)}))
+    return jax.tree.leaves(params)[names.index(path)]
 
 
 def nbytes(params) -> int:
